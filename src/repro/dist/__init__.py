@@ -3,16 +3,9 @@
 ``repro.dist.context`` binds the *logical* activation axes model code
 references ("dp", "tp") to concrete mesh axes; ``repro.dist.sharding``
 holds the placement policies (parameter, batch and cache specs) the
-launchers feed to ``jax.jit``.  Importing the package installs the
-small compatibility aliases (:mod:`repro.dist.compat`) that let the
-codebase target the modern ``jax.set_mesh`` / ``jax.shard_map`` API on
-the pinned 0.4.x toolchain.
+launchers feed to ``jax.jit``.
 """
 
-from . import compat as _compat
-
-_compat.install()
-
-from . import context, sharding  # noqa: E402
+from . import context, sharding
 
 __all__ = ["context", "sharding"]

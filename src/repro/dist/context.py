@@ -3,9 +3,9 @@
 Model code never names mesh axes directly: it constrains activations
 against the *logical* axes ``"dp"`` (batch/data parallel — possibly a
 tuple of mesh axes) and ``"tp"`` (tensor/model parallel), and the
-launcher binds those once via :func:`set_activation_axes`.  With no
-binding in place every :func:`constrain` is the identity, so the same
-model code runs unsharded (CPU tests, the serving engine, eval
+launcher binds those for the span of a ``with`` block via
+:func:`act_ctx`.  With no binding in place every :func:`constrain` is
+the identity, so the same model code runs unsharded (CPU tests, the serving engine, eval
 scripts) without carrying mesh plumbing.
 """
 
@@ -18,12 +18,7 @@ from typing import Any, Sequence
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from . import compat as _compat
-
-_compat.install()
-
 __all__ = [
-    "set_activation_axes",
     "activation_axes",
     "mesh",
     "dp_size",
@@ -39,16 +34,6 @@ def _get() -> dict[str, Any]:
     if not hasattr(_state, "v"):
         _state.v = {"dp": None, "tp": None, "mesh": None}
     return _state.v
-
-
-def set_activation_axes(*, dp=None, tp=None, mesh=None) -> None:
-    """Bind (or clear, with all-None) the logical activation axes.
-
-    ``dp`` may be a single mesh-axis name or a tuple of names (multi-pod
-    data parallelism); ``tp`` is a single mesh-axis name.
-    """
-    s = _get()
-    s["dp"], s["tp"], s["mesh"] = dp, tp, mesh
 
 
 def activation_axes() -> tuple[Any, Any]:
@@ -105,10 +90,15 @@ def constrain(x, axes: Sequence[Any]):
 
 @contextmanager
 def act_ctx(*, dp=None, tp=None, mesh=None):
-    """Scoped :func:`set_activation_axes` (restores the previous binding)."""
+    """Bind the logical activation axes inside the ``with`` block and
+    restore the previous binding on exit.
+
+    ``dp`` may be a single mesh-axis name or a tuple of names (multi-pod
+    data parallelism); ``tp`` is a single mesh-axis name.
+    """
     s = _get()
     prev = (s["dp"], s["tp"], s["mesh"])
-    set_activation_axes(dp=dp, tp=tp, mesh=mesh)
+    s["dp"], s["tp"], s["mesh"] = dp, tp, mesh
     try:
         yield
     finally:

@@ -1,7 +1,7 @@
 """Pallas TPU decode-attention (flash-decode) kernel.
 
 One new query token per (batch, head) against a long KV cache:
-q: (BH, 1, D), k/v: (BH, T, D), valid length per row: (BH, 1).
+q: (BH, 1, D), k/v: (BH, T, D), valid length per row: (BH,).
 
 Grid: ``(BH, T // block_k)`` — the KV axis is the *sequential* grid
 dimension (TPU executes the last grid axis in order), so partial
@@ -9,6 +9,11 @@ dimension (TPU executes the last grid axis in order), so partial
 are finalised by the last program.  Long caches therefore stream
 through VMEM in ``block_k`` tiles; this is the kernel shape that makes
 the ``long_500k`` cells viable on the sequence-sharded cache layout.
+
+The per-row lengths are scalar-prefetched into SMEM (a (BH, 1) VMEM
+block would break the TPU's (8, 128) tiling rule), and the running
+max / sum live in lane-wide ``(1, 128)`` scratch rows whose lanes all
+hold the same value.
 """
 
 from __future__ import annotations
@@ -18,20 +23,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU scratch memory spaces; interpret mode accepts them too
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["decode_attention_bh"]
 
 _NEG_INF = -1e30
+_LANES = 128
 
 
-def _kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref, acc_ref, *,
+def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             scale: float, block_k: int):
+    bh = pl.program_id(0)
     ki = pl.program_id(1)
     n_k = pl.num_programs(1)
 
@@ -44,24 +46,24 @@ def _kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref, acc_ref, *,
     q = q_ref[...].astype(jnp.float32) * scale            # (1, D)
     k = k_ref[...].astype(jnp.float32)                    # (bk, D)
     v = v_ref[...].astype(jnp.float32)
-    valid_len = len_ref[0]
+    valid_len = len_ref[bh]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (1, bk)
     idx = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
     s = jnp.where(idx < valid_len, s, _NEG_INF)
 
-    m_prev, l_prev = m_ref[...], l_ref[...]               # (1,), (1,)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev, l_prev = m_ref[...], l_ref[...]               # (1, 128) each
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new[:, :1])
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_prev * corr + jnp.sum(p, axis=1)
+    l_ref[...] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
     m_ref[...] = m_new
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+    acc_ref[...] = acc_ref[...] * corr[:, :1] + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())))
 
     @pl.when(ki == n_k - 1)
     def _final():
         o_ref[...] = (acc_ref[...] /
-                      jnp.maximum(l_ref[...], 1e-30)[:, None]
+                      jnp.maximum(l_ref[...][:, :1], 1e-30)
                       ).astype(o_ref.dtype)
 
 
@@ -69,29 +71,32 @@ def decode_attention_bh(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         lengths: jnp.ndarray, *, scale: float,
                         block_k: int = 512,
                         interpret: bool = False) -> jnp.ndarray:
-    """q: (BH, 1, D), k/v: (BH, T, D), lengths: (BH, 1) -> (BH, 1, D)."""
+    """q: (BH, 1, D), k/v: (BH, T, D), lengths: (BH,) -> (BH, 1, D)."""
     BH, _, D = q.shape
     T = k.shape[1]
     block_k = min(block_k, T)
     assert T % block_k == 0
-    grid = (BH, T // block_k)
     kernel = functools.partial(_kernel, scale=scale, block_k=block_k)
-    scratch = [
-        _VMEM((1,), jnp.float32),      # m
-        _VMEM((1,), jnp.float32),      # l
-        _VMEM((1, D), jnp.float32),    # acc
-    ]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(BH, T // block_k),
+        in_specs=[
+            pl.BlockSpec((None, 1, D), lambda bh, ki, lens: (bh, 0, 0)),
+            pl.BlockSpec((None, block_k, D),
+                         lambda bh, ki, lens: (bh, ki, 0)),
+            pl.BlockSpec((None, block_k, D),
+                         lambda bh, ki, lens: (bh, ki, 0)),
+        ],
+        out_specs=pl.BlockSpec((None, 1, D), lambda bh, ki, lens: (bh, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((1, _LANES), jnp.float32),   # m
+            pltpu.VMEM((1, _LANES), jnp.float32),   # l
+            pltpu.VMEM((1, D), jnp.float32),        # acc
+        ],
+    )
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, 1, D), lambda bh, ki: (bh, 0, 0)),
-            pl.BlockSpec((None, block_k, D), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((None, block_k, D), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((None, 1), lambda bh, ki: (bh, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, 1, D), lambda bh, ki: (bh, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, 1, D), q.dtype),
-        scratch_shapes=scratch,
         interpret=interpret,
-    )(q, k, v, lengths)
+    )(lengths.astype(jnp.int32), q, k, v)

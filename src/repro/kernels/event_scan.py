@@ -22,8 +22,9 @@ Three pieces, same float32 arithmetic:
 * :func:`event_times_pallas` — ``pl.pallas_call`` with ``grid=(B,)``,
   one ``(1, n)`` order row per program and broadcast table operands;
   ``interpret=True`` (the default off-TPU) runs the same kernel on CPU
-  for tier-1 tests, the compiled path is exercised under the
-  ``requires_jax_device`` marker.
+  for tier-1 tests; the compiled path is tested only where JAX runs
+  on an accelerator.  The TPU compiler refuses it as written: its
+  ``(1, n)`` row block is not (8, 128)-aligned.
 
 float32 deviations from the float64 reference, all documented and
 property-tested (``tests/test_batched.py``):

@@ -77,7 +77,7 @@ def decode_attention(q, k, v, lengths, *,
     qf, D0 = _pad_d(qf)
     kf, _ = _pad_d(kf)
     vf, _ = _pad_d(vf)
-    lens = jnp.repeat(lengths[:, None], H, axis=1).reshape(B * H, 1)
+    lens = jnp.repeat(lengths, H)
     out = decode_attention_bh(qf, kf, vf, lens, scale=scale,
                               interpret=interpret)
     return out[..., :D0].reshape(B, H, D)

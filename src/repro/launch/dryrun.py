@@ -121,11 +121,10 @@ def dryrun_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
               "mesh": "x".join(map(str, mesh.devices.shape)),
               "n_devices": mesh.devices.size}
 
-    from repro.dist.context import set_activation_axes
-    with jax.set_mesh(mesh):
+    from repro.dist.context import act_ctx
+    dp = batch_spec(mesh)
+    with jax.set_mesh(mesh), act_ctx(dp=dp[0], tp="model", mesh=mesh):
         inp = input_specs(cfg, spec)
-        dp = batch_spec(mesh)
-        set_activation_axes(dp=dp[0], tp="model", mesh=mesh)
         if spec.kind == "train":
             state = state_specs(cfg, with_opt=True, opt_dtype=jnp.bfloat16)
             pspecs = param_specs(state["params"], mesh)

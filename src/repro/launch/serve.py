@@ -14,6 +14,7 @@ import jax
 import numpy as np
 
 from repro.configs import arch_names, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serve import Request, SchedulerPolicy, ServingEngine
 from repro.train.checkpoint import latest_step, restore_checkpoint
@@ -60,6 +61,7 @@ def main(argv=None) -> int:
     ap.add_argument("--max-new-tokens", type=int, default=8)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     stats = serve(args.arch, variant=args.variant,
                   n_requests=args.requests, policy=args.policy,
                   max_len=args.max_len,

@@ -21,8 +21,9 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import arch_names, get_config
 from repro.data import DataConfig, SyntheticLM
-from repro.dist.context import set_activation_axes
+from repro.dist.context import act_ctx
 from repro.dist.sharding import batch_spec, named, param_specs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import transformer as T
 from repro.optim import AdamWConfig, adamw_init
@@ -42,8 +43,7 @@ def train(arch: str, *, variant: str = "smoke", steps: int = 100,
     else:
         mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     dp = batch_spec(mesh)
-    with jax.set_mesh(mesh):
-        set_activation_axes(dp=dp[0], tp="model", mesh=mesh)
+    with jax.set_mesh(mesh), act_ctx(dp=dp[0], tp="model", mesh=mesh):
         key = jax.random.PRNGKey(0)
         params = T.init(key, cfg)
         opt_state = adamw_init(params)
@@ -104,6 +104,7 @@ def main(argv=None) -> int:
     ap.add_argument("--mesh", default="host",
                     choices=["host", "single", "multi"])
     args = ap.parse_args(argv)
+    enable_compile_cache()
     out = train(args.arch, variant=args.variant, steps=args.steps,
                 global_batch=args.batch, seq_len=args.seq,
                 accum=args.accum, lr=args.lr, ckpt_dir=args.ckpt_dir,

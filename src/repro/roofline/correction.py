@@ -40,7 +40,7 @@ def measure_depths(arch: str, shape_name: str) -> dict:
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.configs import SHAPES, get_config
-    from repro.dist.context import set_activation_axes
+    from repro.dist.context import act_ctx
     from repro.dist.sharding import batch_spec, named, param_specs
     from repro.launch.dryrun import _collective_bytes
     from repro.launch.mesh import make_production_mesh
@@ -55,9 +55,8 @@ def measure_depths(arch: str, shape_name: str) -> dict:
     prefix, period = unit_period(cfg_full)
     mesh = make_production_mesh()
     out = {}
-    with jax.set_mesh(mesh):
-        dp = batch_spec(mesh)
-        set_activation_axes(dp=dp[0], tp="model", mesh=mesh)
+    dp = batch_spec(mesh)
+    with jax.set_mesh(mesh), act_ctx(dp=dp[0], tp="model", mesh=mesh):
         for k in (1, 2):
             cfg = cfg_full.replace(n_layers=prefix + k * period)
             inp = input_specs(cfg, spec)

@@ -50,6 +50,11 @@ from .live import LiveComposition
 __all__ = ["Request", "ServingEngine", "SchedulerPolicy",
            "ScheduleCache", "Signature", "build_dag_triples"]
 
+#: One jitted decode step for every engine: engines over the same
+#: config (replicas, repeated runs) share its compilations.  The step
+#: runs where its committed inputs (the engine's parameters) live.
+_decode_step = jax.jit(T.decode_step, static_argnums=(1,))
+
 
 @dataclass
 class Request:
@@ -257,8 +262,6 @@ class ServingEngine:
         self.device = device or make_serving_device()
         self.weights_bytes = 2.0 * self.n_params  # bf16 weight stream
         self.queue: list[Request] = []
-        self._decode_jit = jax.jit(
-            lambda p, t, c, s: T.decode_step(p, cfg, t, c, s))
         self._round_times: list[float] = []
         #: the unified registry (PR 8): cache counters, composer
         #: guard/refine timers and the engine's own phase timers all
@@ -364,20 +367,26 @@ class ServingEngine:
         for r in reqs:
             self.latency.arrive(r.rid)
 
-    def _exec_prefill(self, r: Request) -> None:
-        toks = jnp.asarray(r.prompt, jnp.int32)[None, :]
+    def replay_prefill(self, prompt) -> tuple[jnp.ndarray, object]:
+        """Replay ``prompt`` through the decode step, one token at a
+        time (correctness-first prefill).  Returns the last prompt
+        token's logits, (1, vocab), and the filled cache."""
+        toks = jnp.asarray(prompt, jnp.int32)[None, :]
         cache = T.init_cache(self.cfg, 1, self.max_len)
-        # replay prompt through decode steps (correctness-first prefill)
         for s in range(toks.shape[1]):
-            logits, cache = self._decode_jit(self.params, toks[:, s],
-                                             cache, s)
-        r.cache = cache
-        r.pos = int(toks.shape[1])
+            logits, cache = _decode_step(self.params, self.cfg, toks[:, s],
+                                         cache, s)
+        return logits, cache
+
+    def _exec_prefill(self, r: Request) -> None:
+        logits, r.cache = self.replay_prefill(r.prompt)
+        r.pos = len(r.prompt)
         r.generated.append(int(jnp.argmax(logits[0])))
 
     def _exec_decode(self, r: Request) -> None:
         tok = jnp.asarray([r.generated[-1]], jnp.int32)
-        logits, r.cache = self._decode_jit(self.params, tok, r.cache, r.pos)
+        logits, r.cache = _decode_step(self.params, self.cfg, tok, r.cache,
+                                       r.pos)
         r.pos += 1
         r.generated.append(int(jnp.argmax(logits[0])))
         if (len(r.generated) >= r.max_new_tokens or

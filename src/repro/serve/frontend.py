@@ -196,24 +196,41 @@ class ServingFrontend:
     def build(cls, cfg, params, *, n_replicas: int = 1, policy=None,
               admission: AdmissionPolicy | None = None,
               shared_cache: bool = False, max_len: int = 256,
-              device=None, recorder=None, metrics=None, **engine_kw):
+              device=None, placement=None, recorder=None, metrics=None,
+              **engine_kw):
         """Build a replica pool over one model.
 
         ``shared_cache=True`` gives every replica the same
         :class:`ScheduleCache` (with its own registry); otherwise each
         engine keeps its per-replica cache in its own registry.
+
+        ``placement`` is an optional sequence of ``n_replicas`` JAX
+        devices: replica ``i`` gets its own copy of ``params`` on
+        ``placement[i]``, and its decode steps and KV caches follow
+        those committed parameters.  Without it every replica shares
+        ``params`` where they already are.  (``device`` is the modelled
+        scheduling :class:`~repro.core.resources.DeviceModel`, not a
+        JAX device.)
         """
+        import jax
+
         from .cache import ScheduleCache
         from .engine import SchedulerPolicy, ServingEngine
 
+        if placement is not None and len(placement) != n_replicas:
+            raise ValueError(f"placement names {len(placement)} devices "
+                             f"for {n_replicas} replicas")
         policy = policy or SchedulerPolicy()
         shared = (ScheduleCache(kv_bucket=policy.kv_bucket)
                   if shared_cache else None)
-        engines = [ServingEngine(cfg, params, max_len=max_len,
+        engines = [ServingEngine(cfg,
+                                 params if placement is None else
+                                 jax.device_put(params, placement[i]),
+                                 max_len=max_len,
                                  policy=policy, device=device,
                                  recorder=recorder, schedule_cache=shared,
                                  **engine_kw)
-                   for _ in range(n_replicas)]
+                   for i in range(n_replicas)]
         return cls(engines, admission, metrics=metrics,
                    recorder=recorder)
 

@@ -279,10 +279,20 @@ def test_event_scan_oversized_blocks(dispatch):
                 <= event_scan.F32_EVENT_RTOL
 
 
-@pytest.mark.requires_jax_device
-def test_event_scan_compiled_pallas():
+@pytest.fixture
+def accelerator():
+    """Skip unless JAX runs on a TPU or GPU.  Asked when a test that
+    needs it starts, never at collection: on a machine with a chip,
+    every collecting worker would otherwise load its runtime."""
+    import jax
+    if jax.default_backend() not in ("tpu", "gpu"):
+        pytest.skip("no TPU/GPU jax backend: compiled Pallas path "
+                    "unavailable (interpret-mode tests cover the logic)")
+
+
+def test_event_scan_compiled_pallas(accelerator):
     """The compiled (non-interpret) Pallas dispatch — only meaningful
-    on a real accelerator backend; CPU runners skip via conftest."""
+    on a real accelerator backend."""
     rng = random.Random(7)
     table = ProfileTable.build(_gpu_kernels(rng, 16), GTX580)
     rows = _scan_rows(rng, table, B=4)

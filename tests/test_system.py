@@ -189,7 +189,8 @@ def test_serving_greedy_decode_deterministic():
 # --------------------------------------------------------------------------
 
 def test_moe_distributed_matches_local():
-    from repro.dist.context import act_ctx, set_activation_axes
+    from repro.dist.context import act_ctx
+    from repro.launch.mesh import make_host_mesh
     from repro.models.common import ModelConfig
     from repro.models.moe import MoE
     cfg = ModelConfig(name="m", n_layers=2, d_model=32, n_heads=4,
@@ -199,15 +200,10 @@ def test_moe_distributed_matches_local():
     p = MoE.init(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 32))
     y_local, aux_local = MoE._fwd_local(p, cfg, x)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    from repro.dist.context import set_activation_axes
-    with jax.set_mesh(mesh):
-        set_activation_axes(dp="data", tp="model", mesh=mesh)
-        try:
-            y_ep, aux_ep = jax.jit(
-                lambda pp, xx: MoE._fwd_ep(pp, cfg, xx))(p, x)
-        finally:
-            set_activation_axes(dp=None, tp=None)
+    mesh = make_host_mesh()
+    with jax.set_mesh(mesh), act_ctx(dp="data", tp="model", mesh=mesh):
+        y_ep, aux_ep = jax.jit(
+            lambda pp, xx: MoE._fwd_ep(pp, cfg, xx))(p, x)
     np.testing.assert_allclose(np.asarray(y_local), np.asarray(y_ep),
                                rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(float(aux_local["moe_lb_loss"]),

@@ -1,0 +1,99 @@
+"""Ahead-of-time compiles for a described TPU v5e at qwen1.5-0.5b
+widths: the Pallas kernels of the serving path and the full-width
+decode step.  No chip is needed, and nothing runs: the TPU compiler
+refuses what the chip would refuse (misaligned blocks, unsupported
+primitives, programs that do not fit its memory).
+
+The topology is described inside a fixture, never while a module is
+imported: only one process at a time may load the TPU library.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels import ops
+from repro.models import transformer as T
+
+_HBM_BYTES = 16 * 2 ** 30          # one v5e chip
+_BF = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    with pytest.MonkeyPatch.context() as mp:
+        if "TPU_LOG_DIR" not in os.environ:   # else the compiler logs to /tmp
+            mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # A compile for a described chip is written to the persistent
+        # cache but cannot be read back without one: keep it off.
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", enabled)
+            compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def qwen():
+    return get_config("qwen1.5-0.5b", "full")
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_case(name, cfg, sh):
+    H, Hkv, D, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_model
+    if name == "flash_attention":
+        qkv = _spec((1, 512, H, D), _BF, sh), _spec((1, 512, Hkv, D), _BF, sh)
+        return (lambda q, k, v: ops.flash_attention(q, k, v,
+                                                    interpret=False),
+                (qkv[0], qkv[1], qkv[1]))
+    if name == "decode_attention":
+        kv = _spec((4, 1024, Hkv, D), _BF, sh)
+        return (lambda q, k, v, n: ops.decode_attention(q, k, v, n,
+                                                        interpret=False),
+                (_spec((4, H, D), _BF, sh), kv, kv,
+                 _spec((4,), jnp.int32, sh)))
+    return (lambda x, s: ops.rmsnorm(x, s, interpret=False),
+            (_spec((512, d), _BF, sh), _spec((d,), jnp.float32, sh)))
+
+
+@pytest.mark.parametrize("name", ["flash_attention", "decode_attention",
+                                  "rmsnorm"])
+def test_kernel_compiles_for_v5e(one_chip, qwen, name):
+    fn, args = _kernel_case(name, qwen, one_chip)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_decode_step_fits_one_v5e(one_chip, qwen):
+    def place(tree):
+        return jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+                            tree)
+
+    params = place(jax.eval_shape(lambda k: T.init(k, qwen),
+                                  jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: T.init_cache(qwen, 1, 256)))
+    tok = _spec((1,), jnp.int32, one_chip)
+    pos = _spec((), jnp.int32, one_chip)
+    compiled = jax.jit(T.decode_step, static_argnums=(1,)).lower(
+        params, qwen, tok, cache, pos).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < total < _HBM_BYTES, total
