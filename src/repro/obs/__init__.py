@@ -8,16 +8,22 @@ Six layers:
 * :mod:`repro.obs.metrics` — :class:`MetricsRegistry` with counters /
   gauges / histograms (seeded reservoir p50/p95/p99); the single sink
   behind ``ScheduleCache.stats()``, the composer counters, and the
-  refiners' budget accounting.
+  refiners' budget accounting.  Every timer is also a
+  ``jax.profiler.TraceAnnotation`` span of its series' name, so the
+  timed phases appear in a profiler trace on the device's clock; a
+  timer's metadata (request id, position) rides on the span and is
+  never a label.
 * :mod:`repro.obs.profile` — phase-timing conventions
-  (:data:`PHASES`) and :func:`phase_breakdown` for the per-step
-  compose/guard/refine/execute/audit wall-clock view.
+  (:data:`PHASES`: compose/guard/refine, execute and, inside it, each
+  request's prefill, decode and argmax sync, audit) and
+  :func:`phase_breakdown` for the per-step wall-clock view.
 * :mod:`repro.obs.audit`   — :class:`QualityAuditor`, the online
   Fig.-1 sampler: served compositions scored against K seeded random
   orders under the step's own currency, with the paper's 90th
   percentile as a live SLO floor.
 * :mod:`repro.obs.latency` — :class:`LatencyTracker` (per-request
-  arrival→completion spans with phase attribution, p50/p95/p99 and
+  arrival→completion spans: queue wait to the start of prefill, each
+  request's own execute seconds, shared compose; p50/p95/p99 and
   goodput) and :class:`DriftMonitor` (EWMA modelled-vs-revalidated
   replay drift per cache namespace).
 * :mod:`repro.obs.export`  — :func:`prometheus_text` exposition for

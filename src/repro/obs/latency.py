@@ -7,13 +7,16 @@ reports them can be built.  This module provides both halves:
 * :class:`LatencyTracker` — per-request arrival→completion wall-clock
   spans inside :class:`repro.serve.engine.ServingEngine`, with
   queue/compose/guard/refine/execute attribution.  Queue time is
-  arrival→first-scheduled; each engine step's measured phase wall
-  times are split evenly across the requests served that step (the
-  synchronous engine runs one step at a time, so an even split is the
-  honest attribution — no request makes progress outside its step).
-  Completions feed the ``request_latency_s`` / ``request_queue_s`` /
-  ``request_phase_s{phase=...}`` histograms, whose seeded reservoirs
-  give p50/p95/p99 in :meth:`stats` and in
+  arrival→start of the request's prefill (:meth:`LatencyTracker.start`;
+  a caller that has no prefill to point at, such as the frontend's
+  admission, closes it through :meth:`~LatencyTracker.attribute`), and
+  ``request_queue_s`` is observed when it closes.  Execute time is
+  each request's own prefill and decode seconds
+  (:meth:`~LatencyTracker.charge`); only the step's compose, guard
+  and refine times, which serve every request of the step at once,
+  are split evenly across the requests served.  Completions feed the
+  ``request_latency_s`` / ``request_phase_s{phase=...}`` histograms,
+  whose seeded reservoirs give p50/p95/p99 in :meth:`stats` and in
   ``ServingEngine.stats()["latency"]``.
 
 * :class:`DriftMonitor` — the EWMA modelled-vs-revalidated drift
@@ -39,7 +42,8 @@ import time
 __all__ = ["LatencyTracker", "DriftMonitor"]
 
 #: phase attribution keys, in pipeline order (queue is derived from
-#: arrival→first-scheduled, the rest from engine phase wall deltas)
+#: arrival→start, execute from each request's own calls, the rest
+#: from engine phase wall deltas)
 ATTRIB_PHASES = ("compose", "guard", "refine", "execute")
 
 
@@ -68,18 +72,36 @@ class LatencyTracker:
         self.metrics = metrics
         self.clock = clock
         self._open: dict[int, _Span] = {}
+        self._queue = metrics.histogram("request_queue_s")
 
     def arrive(self, rid: int, t: float | None = None) -> None:
         """A request entered the queue (``ServingEngine.submit``)."""
         if rid not in self._open:
             self._open[rid] = _Span(self.clock() if t is None else t)
 
+    def _close_queue(self, span: _Span, now: float) -> None:
+        span.t_first = now
+        self._queue.observe(now - span.t_arrive)
+
+    def start(self, rid: int, t: float | None = None) -> None:
+        """The request's first work (its prefill) starts: close its
+        queue span at ``t``."""
+        span = self._open.get(rid)
+        if span is not None and span.t_first is None:
+            self._close_queue(span, self.clock() if t is None else t)
+
+    def charge(self, rid: int, phase: str, seconds: float) -> None:
+        """Add ``seconds`` of ``phase`` spent on this request alone."""
+        span = self._open.get(rid)
+        if span is not None:
+            span.phases[phase] += seconds
+
     def attribute(self, rids, phase_s: dict,
                   t: float | None = None) -> None:
         """One engine step served ``rids``; split each measured phase
         wall time (``phase_s``, seconds per phase) evenly across
-        them.  First-time-scheduled requests get their queue span
-        closed at ``t``."""
+        them.  Requests whose queue span is still open get it closed
+        at ``t``."""
         rids = [r for r in rids if r in self._open]
         if not rids:
             return
@@ -88,7 +110,7 @@ class LatencyTracker:
         for rid in rids:
             span = self._open[rid]
             if span.t_first is None:
-                span.t_first = now
+                self._close_queue(span, now)
             for ph, s in share.items():
                 if ph in span.phases:
                     span.phases[ph] += s
@@ -100,10 +122,10 @@ class LatencyTracker:
         if span is None:
             return
         now = self.clock() if t is None else t
+        if span.t_first is None:
+            self._close_queue(span, now)
         m = self.metrics
         m.histogram("request_latency_s").observe(now - span.t_arrive)
-        t_first = span.t_first if span.t_first is not None else now
-        m.histogram("request_queue_s").observe(t_first - span.t_arrive)
         for ph, s in span.phases.items():
             m.histogram("request_phase_s", phase=ph).observe(s)
         m.counter("requests_completed").inc()

@@ -29,6 +29,7 @@ The historical import surface — ``ScheduleCache``, ``Signature``, the
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 
 import jax
@@ -63,6 +64,9 @@ class Request:
     max_new_tokens: int = 16
     # runtime state
     generated: list[int] = field(default_factory=list)
+    #: ``time.perf_counter()`` when each token of ``generated`` was
+    #: read back, one per token
+    token_times: list[float] = field(default_factory=list)
     cache: object = None
     pos: int = 0
     done: bool = False
@@ -303,7 +307,15 @@ class ServingEngine:
         #: by ``submit()`` / ``step()``, exported as
         #: ``run()``-stats ``"latency"`` (p50/p95/p99 + goodput).
         self.latency = LatencyTracker(self.metrics)
-        self._completed_rids: set[int] = set()
+        # hot-path series, resolved once (see repro.obs.profile)
+        m = self.metrics
+        self._steps = m.counter("engine_steps")
+        self._phase = {ph: m.histogram(f"phase_{ph}")
+                       for ph in ("compose", "guard", "refine", "execute",
+                                  "audit", "prefill", "decode", "sync")}
+        self._calls = {k: m.counter("decode_calls", kind=k)
+                       for k in ("prefill", "decode")}
+        self._tokens = m.counter("tokens_emitted")
 
     # -- workload characterisation -------------------------------------
     def _kv_bytes_per_token(self) -> float:
@@ -373,22 +385,40 @@ class ServingEngine:
         token's logits, (1, vocab), and the filled cache."""
         toks = jnp.asarray(prompt, jnp.int32)[None, :]
         cache = T.init_cache(self.cfg, 1, self.max_len)
+        calls = self._calls["prefill"]
         for s in range(toks.shape[1]):
             logits, cache = _decode_step(self.params, self.cfg, toks[:, s],
                                          cache, s)
+            calls.inc()
         return logits, cache
 
+    def _emit(self, r: Request, logits) -> None:
+        """Read back the chosen token (``phase_sync``) and record it
+        with the time it was made."""
+        with self._phase["sync"].time(rid=r.rid):
+            tok = int(jnp.argmax(logits[0]))
+        r.generated.append(tok)
+        r.token_times.append(time.perf_counter())
+        self._tokens.inc()
+
     def _exec_prefill(self, r: Request) -> None:
-        logits, r.cache = self.replay_prefill(r.prompt)
-        r.pos = len(r.prompt)
-        r.generated.append(int(jnp.argmax(logits[0])))
+        self.latency.start(r.rid)
+        with self._phase["prefill"].time(rid=r.rid,
+                                         prompt_len=len(r.prompt)) as t:
+            logits, r.cache = self.replay_prefill(r.prompt)
+            r.pos = len(r.prompt)
+            self._emit(r, logits)
+        self.latency.charge(r.rid, "execute", t.elapsed)
 
     def _exec_decode(self, r: Request) -> None:
-        tok = jnp.asarray([r.generated[-1]], jnp.int32)
-        logits, r.cache = _decode_step(self.params, self.cfg, tok, r.cache,
-                                       r.pos)
-        r.pos += 1
-        r.generated.append(int(jnp.argmax(logits[0])))
+        with self._phase["decode"].time(rid=r.rid, pos=r.pos) as t:
+            tok = jnp.asarray([r.generated[-1]], jnp.int32)
+            logits, r.cache = _decode_step(self.params, self.cfg, tok,
+                                           r.cache, r.pos)
+            self._calls["decode"].inc()
+            r.pos += 1
+            self._emit(r, logits)
+        self.latency.charge(r.rid, "execute", t.elapsed)
         if (len(r.generated) >= r.max_new_tokens or
                 r.pos >= self.max_len - 1):
             r.done = True
@@ -404,21 +434,27 @@ class ServingEngine:
         ``composition="incremental"`` the traced step composes through
         the live frontier instead of the batch pipeline.
 
-        Observability (PR 8-9): the whole composition pipeline is
-        timed under the ``phase_compose`` histogram and the execution
-        loop under ``phase_execute`` (the composer's own
-        ``phase_guard`` / ``phase_refine`` are sub-intervals of
-        compose); sampled steps run the online quality audit under
-        ``phase_audit`` (outside compose, so audit cost never skews
-        the compose-time series); with :attr:`trace` set, each
-        executed round is recorded on the modelled-round timeline;
-        the step's measured phase wall times are attributed to the
-        requests it served (:class:`repro.obs.LatencyTracker`)."""
-        self.metrics.counter("engine_steps").inc()
-        phase0 = {ph: self.metrics.histogram(f"phase_{ph}").total
-                  for ph in ("compose", "guard", "refine", "execute")}
+        Observability: the whole composition pipeline is timed under
+        the ``phase_compose`` histogram and the execution loop under
+        ``phase_execute`` (the composer's own ``phase_guard`` /
+        ``phase_refine`` are sub-intervals of compose); inside execute,
+        each request's replayed prompt is ``phase_prefill``, each decode
+        call ``phase_decode``, and the argmax read-back in either
+        ``phase_sync`` (see :mod:`repro.obs.profile`; every timer is a
+        profiler span too).  Sampled steps run the online quality audit
+        under ``phase_audit`` (outside compose, so audit cost never
+        skews the compose-time series); with :attr:`trace` set, each
+        executed round is recorded on the modelled-round timeline.  A
+        request's queue span closes when its prefill starts, its own
+        prefill and decode seconds are its execute time, and the
+        step's compose time is split across the requests it served
+        (:class:`repro.obs.LatencyTracker`)."""
+        self._steps.inc()
+        phase = self._phase
+        phase0 = {ph: phase[ph].total for ph in ("compose", "guard",
+                                                 "refine")}
         traced = None
-        with self.metrics.timer("phase_compose"):
+        with phase["compose"].time():
             if self.policy.respect_deps:
                 triples, traced = self._work_items_dag()
                 if not triples:
@@ -439,7 +475,7 @@ class ServingEngine:
         # rounds, on deterministically sampled steps only.
         aud = self.composer.auditor
         if aud.sample_step():
-            with self.metrics.timer("phase_audit"):
+            with phase["audit"].time():
                 if traced is not None:
                     aud.audit_dag(rounds, traced, arch=self.cfg.name,
                                   kind=self.policy.kind)
@@ -449,7 +485,7 @@ class ServingEngine:
                                    arch=self.cfg.name,
                                    kind=self.policy.kind)
         n = 0
-        with self.metrics.timer("phase_execute"):
+        with phase["execute"].time():
             for rd in rounds:
                 rt = time_of(rd)
                 self._round_times.append(rt)
@@ -469,19 +505,17 @@ class ServingEngine:
                     elif kind == "decode":
                         self._exec_decode(r)
                 n += 1
-        # Latency accounting: split this step's measured phase wall
-        # times across the requests it served ("compose" net of its
-        # guard/refine sub-intervals, so the four shares partition the
-        # step), then close spans for requests that just finished.
-        delta = {ph: self.metrics.histogram(f"phase_{ph}").total - t0
-                 for ph, t0 in phase0.items()}
+        # Latency accounting: split this step's shared phase wall times
+        # across the requests it served ("compose" net of its
+        # guard/refine sub-intervals), then close the spans of requests
+        # that just finished (a span closes once: complete() drops it).
+        delta = {ph: phase[ph].total - t0 for ph, t0 in phase0.items()}
         delta["compose"] = max(
             0.0, delta["compose"] - delta["guard"] - delta["refine"])
         served = {r.rid: r for rd in rounds for _, r, _ in rd}
         self.latency.attribute(served.keys(), delta)
         for rid, r in served.items():
-            if r.done and rid not in self._completed_rids:
-                self._completed_rids.add(rid)
+            if r.done:
                 self.latency.complete(rid, tokens=len(r.generated))
         return n
 
