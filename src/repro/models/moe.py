@@ -15,6 +15,18 @@ Design constraints:
   8 experts on a 16-way axis),
 * router computed in f32 with load-balance + z losses (returned as
   aux so the train step can weight them).
+
+Without a mesh, the input's static shape picks one of two local paths
+(:meth:`MoE.local_path`).  The grouped buffer multiplies all ``E``
+experts' weights, whatever the routing; when the ``T·K`` token-expert
+assignments are fewer than ``E`` (decode: one token, ``K < E``) the
+gathered path instead reads each routed expert's matrices by its
+scalar id, so only ``T·K`` experts' weights are streamed, and drops
+nothing.  On that range an expert receives at most ``T`` tokens; where
+``T`` is within the grouped buffer's minimum capacity of 8 (always, for
+``E <= 9K``: Mixtral's 8 experts, top-2, give ``T <= 3``) the grouped
+path drops nothing either, and both compute the same sums.
+Training-size inputs and the distributed paths stay grouped.
 """
 
 from __future__ import annotations
@@ -39,6 +51,31 @@ def _expert_ffn(p: PyTree, x: jnp.ndarray, act: str) -> jnp.ndarray:
     else:
         h = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", x, wg))
     return jnp.einsum("ecf,efd->ecd", h, wd)
+
+
+def _gathered_ffn(p: PyTree, xt: jnp.ndarray, expert_ids: jnp.ndarray,
+                  gates: jnp.ndarray, act: str) -> jnp.ndarray:
+    """Routed experts of each token, read one by one: xt (T, d),
+    expert_ids and gates (T, K) -> (T, d).
+
+    Each expert's matrices are a dynamic slice at its scalar id, which
+    XLA fuses into the matmul that reads it, so only the T·K routed
+    experts' weights are streamed (a gather along the expert axis
+    would copy them first).  The loop is unrolled: T·K < E bounds it.
+    Outputs are combined as the grouped path does: each in ``xt.dtype``,
+    times its gate in ``xt.dtype``, summed token-major in k order."""
+    T, K = expert_ids.shape
+    g = gates.astype(xt.dtype)
+    rows = []
+    for t in range(T):
+        row = None
+        for k in range(K):
+            w = {n: jax.lax.dynamic_index_in_dim(p[n], expert_ids[t, k])
+                 for n in ("w_gate", "w_up", "w_down")}
+            y = _expert_ffn(w, xt[None, t:t + 1], act)[0, 0] * g[t, k]
+            row = y if row is None else row + y
+        rows.append(row)
+    return jnp.stack(rows)
 
 
 class MoE:
@@ -72,6 +109,15 @@ class MoE:
         return max(8, -(-c // 8) * 8)  # pad to multiple of 8
 
     @staticmethod
+    def local_path(cfg: ModelConfig, n_tokens: int) -> str:
+        """The local path ``n_tokens`` tokens take: ``"gathered"`` while
+        their ``T·K`` assignments name fewer expert matrices than the
+        grouped buffer's ``E``, else ``"grouped"``."""
+        if n_tokens * cfg.top_k < cfg.n_experts:
+            return "gathered"
+        return "grouped"
+
+    @staticmethod
     def fwd(p: PyTree, cfg: ModelConfig, x: jnp.ndarray
             ) -> tuple[jnp.ndarray, dict]:
         """x: (B, S, d) -> (y, aux_losses).
@@ -102,59 +148,37 @@ class MoE:
         E, K = cfg.n_experts, cfg.top_k
         T = B * S
         xt = x.reshape(T, d)
-        C = MoE.capacity(cfg, T)
 
-        logits = (xt.astype(jnp.float32)
-                  @ p["router"]["w"].astype(jnp.float32))      # (T, E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, expert_ids = jax.lax.top_k(probs, K)        # (T, K)
-        gate_vals = gate_vals / jnp.sum(gate_vals, -1, keepdims=True)
+        if MoE.local_path(cfg, T) == "gathered":
+            logits, probs, gate_vals, flat_e = MoE._route(p, cfg, xt)
+            y = _gathered_ffn(p["experts"], xt, flat_e.reshape(T, K),
+                              gate_vals, cfg.act)
+            keep = jnp.ones(flat_e.shape, bool)
+        else:
+            C = MoE.capacity(cfg, T)
+            logits, probs, gate_vals, flat_e, ranks, keep = \
+                MoE._route_local(p, cfg, xt, C)
+            slot = flat_e * C + jnp.where(keep, ranks, 0)      # (T*K,)
+            token_idx = jnp.repeat(jnp.arange(T), K)
+            # Scatter tokens into the (E*C, d) buffer (dropped -> slot 0
+            # masked).
+            buf = jnp.zeros((E * C, d), x.dtype)
+            contrib = jnp.where(keep[:, None], xt[token_idx], 0.0)
+            buf = buf.at[slot].add(contrib, mode="drop")
+            buf = buf.reshape(E, C, d)
 
-        # ---- slot assignment without (T, E) one-hots ------------------
-        flat_e = expert_ids.reshape(-1)                        # (T*K,)
-        # Priority: earlier tokens win capacity (GShard semantics).
-        order = jnp.argsort(flat_e, stable=True)               # group by expert
-        sorted_e = flat_e[order]
-        # rank within expert group = index - start(expert)
-        counts = jnp.bincount(sorted_e, length=E)              # (E,)
-        starts = jnp.cumsum(counts) - counts
-        ranks_sorted = jnp.arange(T * K) - starts[sorted_e]
-        ranks = jnp.zeros_like(ranks_sorted).at[order].set(ranks_sorted)
-        keep = ranks < C                                       # (T*K,)
+            y_buf = _expert_ffn(p["experts"], buf, cfg.act)    # (E, C, d)
 
-        slot = flat_e * C + jnp.where(keep, ranks, 0)          # (T*K,)
-        token_idx = jnp.repeat(jnp.arange(T), K)
-        # Scatter tokens into the (E*C, d) buffer (dropped -> slot 0 masked).
-        buf = jnp.zeros((E * C, d), x.dtype)
-        contrib = jnp.where(keep[:, None], xt[token_idx], 0.0)
-        buf = buf.at[slot].add(contrib, mode="drop")
-        buf = buf.reshape(E, C, d)
-
-        y_buf = _expert_ffn(p["experts"], buf, cfg.act)        # (E, C, d)
-
-        # Combine: gather each kept assignment's output and weight by gate.
-        y_flat = y_buf.reshape(E * C, d)[slot]                 # (T*K, d)
-        w = jnp.where(keep, gate_vals.reshape(-1), 0.0).astype(x.dtype)
-        y = jnp.zeros((T, d), x.dtype).at[token_idx].add(y_flat * w[:, None])
+            # Combine: gather each kept assignment's output and weight
+            # by gate.
+            y_flat = y_buf.reshape(E * C, d)[slot]             # (T*K, d)
+            w = jnp.where(keep, gate_vals.reshape(-1), 0.0).astype(x.dtype)
+            y = jnp.zeros((T, d), x.dtype).at[token_idx].add(
+                y_flat * w[:, None])
 
         if "shared" in p:
-            from .common import dense
-            sh = p["shared"]
-            if cfg.act == "swiglu":
-                h = jax.nn.silu(dense(sh["w_gate"], xt)) * dense(sh["w_up"], xt)
-            else:
-                h = jax.nn.gelu(dense(sh["w_gate"], xt))
-            y = y + dense(sh["w_down"], h)
-
-        # ---- aux losses ----------------------------------------------
-        me = jnp.mean(probs, axis=0)                           # (E,)
-        ce = jnp.mean(
-            (jnp.bincount(flat_e, length=E) / (T * K)).astype(jnp.float32))
-        frac = jnp.bincount(flat_e, length=E).astype(jnp.float32) / (T * K)
-        lb_loss = E * jnp.sum(frac * me)
-        z_loss = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
-        aux = {"moe_lb_loss": lb_loss, "moe_z_loss": z_loss,
-               "moe_drop_frac": 1.0 - jnp.mean(keep.astype(jnp.float32))}
+            y = y + MoE._shared_tp(p, cfg, xt, None)
+        aux = MoE._aux_of(cfg, logits, probs, flat_e, keep, ())
         return y.reshape(B, S, d), aux
 
     # ------------------------------------------------------------------
@@ -163,15 +187,23 @@ class MoE:
     # ------------------------------------------------------------------
 
     @staticmethod
+    def _route(p, cfg, xt):
+        """Router in f32: logits and probs (t, E), the renormalised
+        top-k gates (t, K) and their expert ids, flattened (t*K,)."""
+        logits = xt.astype(jnp.float32) @ p["router"]["w"].astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, expert_ids = jax.lax.top_k(probs, cfg.top_k)
+        gate_vals = gate_vals / jnp.sum(gate_vals, -1, keepdims=True)
+        return logits, probs, gate_vals, expert_ids.reshape(-1)
+
+    @staticmethod
     def _route_local(p, cfg, xt, capacity):
         """Shared routing: top-k, capacity ranks.  xt: (t, d) local."""
         E, K = cfg.n_experts, cfg.top_k
         t = xt.shape[0]
-        logits = xt.astype(jnp.float32) @ p["router"]["w"].astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, expert_ids = jax.lax.top_k(probs, K)
-        gate_vals = gate_vals / jnp.sum(gate_vals, -1, keepdims=True)
-        flat_e = expert_ids.reshape(-1)
+        logits, probs, gate_vals, flat_e = MoE._route(p, cfg, xt)
+        # Priority: earlier tokens win capacity (GShard semantics); the
+        # rank within an expert's group is index - start(expert).
         order = jnp.argsort(flat_e, stable=True)
         sorted_e = flat_e[order]
         counts = jnp.bincount(sorted_e, length=E)
@@ -201,7 +233,8 @@ class MoE:
 
     @staticmethod
     def _shared_tp(p, cfg, xt, tp_axis):
-        """Shared experts with d_ff tensor-parallel over ``tp_axis``."""
+        """Shared experts with d_ff tensor-parallel over ``tp_axis``
+        (``None``: unsharded, the local path)."""
         if "shared" not in p:
             return 0.0
         sh = p["shared"]

@@ -48,6 +48,12 @@ def _has_ff(cfg: ModelConfig, i: int) -> bool:
     return kind in ("attn", "mamba") and (cfg.d_ff > 0 or cfg.is_moe_layer(i))
 
 
+def n_moe_layers(cfg: ModelConfig) -> int:
+    """Mixture-of-experts layers one pass through the model runs."""
+    return sum(_has_ff(cfg, i) and cfg.is_moe_layer(i)
+               for i in range(cfg.n_layers))
+
+
 def _layer_sig(cfg: ModelConfig, i: int) -> tuple:
     return (cfg.layer_kind(i), cfg.is_moe_layer(i), _has_ff(cfg, i))
 

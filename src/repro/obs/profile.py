@@ -31,8 +31,10 @@ clock, nested as below:
 
 Span metadata is never a registry label: the histograms stay one
 series per phase.  The engine also counts ``decode_calls{kind=prefill}``
-/ ``{kind=decode}`` (one per jitted decode-step call) and
-``tokens_emitted``.
+/ ``{kind=decode}`` (one per jitted decode-step call),
+``tokens_emitted`` and, for a mixture-of-experts model,
+``moe_dispatch{path=gathered|grouped}`` (its MoE layers per call,
+under the local path a one-token call takes).
 
 :func:`phase_breakdown` turns a registry into the per-step view
 ``benchmarks/serving.py`` prints.  Refiners report their own scoring

@@ -43,6 +43,7 @@ from repro.graph.kernel_graph import trace_arch
 from repro.obs import LatencyTracker, MetricsRegistry, phase_breakdown
 from repro.models import transformer as T
 from repro.models.common import ModelConfig
+from repro.models.moe import MoE
 
 from .cache import ScheduleCache, Signature
 from .composer import Composer
@@ -315,6 +316,11 @@ class ServingEngine:
                                   "audit", "prefill", "decode", "sync")}
         self._calls = {k: m.counter("decode_calls", kind=k)
                        for k in ("prefill", "decode")}
+        #: MoE layers a call runs, counted under the local path a
+        #: one-token call takes (``MoE.local_path``); none if dense
+        self._n_moe = T.n_moe_layers(cfg)
+        self._moe = (m.counter("moe_dispatch", path=MoE.local_path(cfg, 1))
+                     if self._n_moe else None)
         self._tokens = m.counter("tokens_emitted")
 
     # -- workload characterisation -------------------------------------
@@ -385,12 +391,18 @@ class ServingEngine:
         token's logits, (1, vocab), and the filled cache."""
         toks = jnp.asarray(prompt, jnp.int32)[None, :]
         cache = T.init_cache(self.cfg, 1, self.max_len)
-        calls = self._calls["prefill"]
         for s in range(toks.shape[1]):
-            logits, cache = _decode_step(self.params, self.cfg, toks[:, s],
-                                         cache, s)
-            calls.inc()
+            logits, cache = self._call("prefill", toks[:, s], cache, s)
         return logits, cache
+
+    def _call(self, kind: str, tok, cache, pos):
+        """One ``decode_step`` call, counted under ``decode_calls{kind}``
+        and, per MoE layer, under ``moe_dispatch{path}``."""
+        out = _decode_step(self.params, self.cfg, tok, cache, pos)
+        self._calls[kind].inc()
+        if self._moe is not None:
+            self._moe.inc(self._n_moe)
+        return out
 
     def _emit(self, r: Request, logits) -> None:
         """Read back the chosen token (``phase_sync``) and record it
@@ -413,9 +425,7 @@ class ServingEngine:
     def _exec_decode(self, r: Request) -> None:
         with self._phase["decode"].time(rid=r.rid, pos=r.pos) as t:
             tok = jnp.asarray([r.generated[-1]], jnp.int32)
-            logits, r.cache = _decode_step(self.params, self.cfg, tok,
-                                           r.cache, r.pos)
-            self._calls["decode"].inc()
+            logits, r.cache = self._call("decode", tok, r.cache, r.pos)
             r.pos += 1
             self._emit(r, logits)
         self.latency.charge(r.rid, "execute", t.elapsed)

@@ -196,3 +196,27 @@ def test_timer_metadata_rides_on_the_span_not_a_label():
     assert sorted(m.snapshot()) == sorted(
         f"phase_x.{k}" for k in ("count", "total_s", "mean_s", "min_s",
                                  "max_s", "p50_s", "p95_s", "p99_s"))
+
+
+@pytest.mark.parametrize("arch,path,n_moe", [
+    ("mixtral-8x7b", "gathered", 4),     # every layer routes, top-2 of 4
+    ("jamba-v0.1-52b", "gathered", 4),   # every other layer of 8
+    ("qwen1.5-0.5b", None, 0),           # dense: nothing to count
+])
+def test_moe_dispatch_counts_each_calls_moe_layers(arch, path, n_moe):
+    cfg = get_config(arch, "smoke")
+    assert T.n_moe_layers(cfg) == n_moe
+    eng = ServingEngine(cfg, T.init(jax.random.PRNGKey(0), cfg), max_len=16)
+    prompt = np.random.default_rng(5).integers(0, cfg.vocab, size=3)
+    eng.submit([Request(0, prompt, max_new_tokens=3)])
+    while eng.step():
+        pass
+    snap = eng.metrics.snapshot()
+    calls = snap["decode_calls{kind=prefill}"] + \
+        snap["decode_calls{kind=decode}"]
+    assert calls == 3 + 2
+    counted = {k: v for k, v in snap.items() if k.startswith("moe_dispatch")}
+    if path is None:
+        assert counted == {}
+    else:
+        assert counted == {f"moe_dispatch{{path={path}}}": calls * n_moe}

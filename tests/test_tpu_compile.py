@@ -1,8 +1,10 @@
 """Ahead-of-time compiles for a described TPU v5e at qwen1.5-0.5b
 widths: the Pallas kernels of the serving path and the full-width
-decode step.  No chip is needed, and nothing runs: the TPU compiler
-refuses what the chip would refuse (misaligned blocks, unsupported
-primitives, programs that do not fit its memory).
+decode step; and one MoE layer at mixtral-8x7b widths, whose
+one-token call must read only its routed experts.  No chip is needed,
+and nothing runs: the TPU compiler refuses what the chip would refuse
+(misaligned blocks, unsupported primitives, programs that do not fit
+its memory).
 
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library.
@@ -18,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels import ops
 from repro.models import transformer as T
+from repro.models.moe import MoE
 
 _HBM_BYTES = 16 * 2 ** 30          # one v5e chip
 _BF = jnp.bfloat16
@@ -97,3 +100,27 @@ def test_decode_step_fits_one_v5e(one_chip, qwen):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < total < _HBM_BYTES, total
+
+
+def test_one_token_moe_reads_only_the_routed_experts(one_chip, monkeypatch):
+    """At T = 1 the gathered path streams the top-2 experts' weights,
+    a quarter of the grouped buffer's 8; a gather that copied the
+    weights first would read more than the grouped path, not less."""
+    cfg = get_config("mixtral-8x7b", "full")
+    p = jax.tree.map(lambda a: _spec(a.shape, _BF, one_chip),
+                     jax.eval_shape(lambda k: MoE.init(k, cfg),
+                                    jax.random.PRNGKey(0)))
+    x = _spec((1, 1, cfg.d_model), _BF, one_chip)
+
+    def bytes_accessed():
+        fn = jax.jit(lambda p, x: MoE._fwd_local(p, cfg, x)[0])
+        cost = fn.lower(p, x).compile().cost_analysis()
+        return (cost[0] if isinstance(cost, list) else cost)["bytes accessed"]
+
+    assert MoE.local_path(cfg, 1) == "gathered"
+    gathered = bytes_accessed()
+    monkeypatch.setattr(MoE, "local_path",
+                        staticmethod(lambda cfg, n: "grouped"))
+    grouped = bytes_accessed()
+    routed = 3 * cfg.top_k * cfg.d_model * cfg.moe_d_ff * 2      # bf16
+    assert routed <= gathered <= 0.3 * grouped, (gathered, grouped)
