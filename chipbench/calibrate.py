@@ -134,6 +134,8 @@ def readings(cell, args, out) -> None:
         if "gaps" in ctx:
             rec.update(check.gap_numbers(ctx["gaps"]))
             rec["widest"] = _widest(cell, ctx)
+            rec["last_position"] = max(len(r.prompt) + len(r.tokens) - 2
+                                       for r in ctx["picked"])
         if seed in control and "gaps" in ctx:
             t1 = time.perf_counter()
             g = check.gaps(ctx["reference"], ctx["picked"], ctx["length"],

@@ -62,10 +62,9 @@ def sample(finished: list[Served], seed: int, min_tokens: int,
 
 
 def family(config: dict, bench_dir=spec.BENCH_DIR):
-    """The module of the configuration's family, found by its
+    """The reference module of the configuration's family, found by its
     ``model_type``: ``<bench_dir>/reference/<model_type>.py``."""
-    return spec.load_module(bench_dir / "reference" /
-                            f"{config['model_type']}.py")
+    return spec.load_family("reference", config, bench_dir)
 
 
 def reference_for(config: dict, weights: dict, bench_dir=spec.BENCH_DIR):

@@ -19,11 +19,17 @@ window with no first token when the window closes enters the
 time-to-first-token tail with its elapsed time, and a live request's
 open gap enters the token-gap tail the same way, so a stall cannot
 hide.
+
+A traced run profiles a sub-window of the window. The profiler's stop
+writes the trace out, which takes tens of seconds on the chip; that
+interval is left out of the window's clock, so the steps still run for
+the window's length and the requests the check compares still finish.
 """
 
 from __future__ import annotations
 
 import collections
+import math
 import os
 import shutil
 import sys
@@ -37,7 +43,8 @@ import numpy as np
 
 from . import check, counts, loadgen, program, spec, trace_reduce
 
-__all__ = ["SPANS", "Run", "run_cell", "read_metrics", "percentile"]
+__all__ = ["SPANS", "Run", "run_cell", "call_work", "read_metrics",
+           "percentile"]
 
 #: host spans written into the profiler trace; they name idle gaps
 SPANS = ("engine.step", "await_arrival", "retire", "submit")
@@ -73,12 +80,16 @@ class Run:
     correct: bool = False
     #: (window time, requests submitted and not finished) after each step
     backlog: list = field(default_factory=list)
+    #: positions held in the live requests' caches over the positions
+    #: their caches reserve (``max_len`` each), summed over the window's
+    #: steps
+    kv_in_use: float | None = None
     #: the window's five longest steps: (seconds, start in the window,
     #: live requests, decode calls, compose s, execute s)
     longest_steps: list = field(default_factory=list)
     #: what the metric readers read: ``run`` (this object) and, in a
-    #: traced run, ``trace``, ``counters``, ``positions``, ``shape`` and
-    #: ``peaks``
+    #: traced run, ``trace``, ``counters``, ``positions``, ``work``
+    #: (:func:`call_work`) and ``peaks``
     ctx: dict = field(default_factory=dict)
 
 
@@ -187,7 +198,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     from repro.serve import Request as EngineRequest
 
     c, mix = cell.config, cell.traffic
-    cfg = program.model_config(c, cell.config_name)
+    cfg = program.model_config(c, cell.config_name, cell.bench_dir)
     max_len = int(mix["max_len"])
     vocab = cfg.vocab
     device = device or jax.devices()[0]
@@ -253,14 +264,19 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
 
     if closed:
         conc = int(mix["concurrency"])
-        submit(source.take(conc))
-        _say(f"prefilling {conc} requests in set-up")
+        first = source.take(conc)
+        if mix.get("aged_start"):
+            first = loadgen.aged(first, mix, seed, vocab)
+        submit(first)
+        _say(f"prefilling {conc} requests in set-up "
+             f"({sum(len(o.prompt) for o in first)} positions)")
         step_and_stamp()
 
     counter = _CompileCounter()
     tracer = _Tracer(float(mix["trace"]["start_s"]),
                      float(mix["trace"]["length_s"])) if trace else None
     tokens_in_window = 0
+    kv_used = kv_held = 0
     backlog: list[tuple] = []
     late: list[float] = []
     pending = collections.deque() if closed else collections.deque(offered)
@@ -269,10 +285,21 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
 
     counter.on = True
     clock0 = time.perf_counter()
+    # the profiler's stop writes the trace out, for tens of seconds, in
+    # the middle of the window; that interval is no window time
+    paused, paused_until = 0.0, math.inf
+
+    def window_time(t: float) -> float:
+        return t - clock0 - (paused if t >= paused_until else 0.0)
+
     now = 0.0
     while now < seconds:
-        if tracer:
+        if tracer and tracer.state != "done":
+            t0 = time.perf_counter()
             tracer.at(now, engine.metrics, calls)
+            if tracer.state == "done":
+                paused_until = time.perf_counter()
+                paused = paused_until - t0
         if closed:
             with jax.profiler.TraceAnnotation("submit"):
                 submit(source.take(int(mix["concurrency"]) - len(live)))
@@ -283,19 +310,21 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
             if due:
                 with jax.profiler.TraceAnnotation("submit"):
                     submit(due)
-                    t_sub = time.perf_counter() - clock0
+                    t_sub = window_time(time.perf_counter())
                     late.extend(t_sub - o.due for o in due)
             if not live:
                 nxt = pending[0].due if pending else seconds
                 with jax.profiler.TraceAnnotation("await_arrival"):
                     time.sleep(max(0.0, min(nxt, seconds) - now))
-                now = time.perf_counter() - clock0
+                now = window_time(time.perf_counter())
                 continue
         t, n = step_and_stamp()
-        now = t - clock0
+        now = window_time(t)
         tokens_in_window += n
         retire()
         backlog.append((now, len(live)))
+        kv_used += sum(r.pos for _, r in live.values())
+        kv_held += len(live) * max_len
     window_s = now
     if tracer:
         tracer.stop(engine.metrics, calls)
@@ -303,12 +332,13 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
 
     run = Run(setup_s=setup_s, window_s=window_s, attempted=len(stamps),
               failed=0, tokens=tokens_in_window, late_s=late,
-              compiles_in_window=counter.n, backlog=backlog)
-    run.longest_steps = [(d, t0 - clock0, *rest) for d, t0, *rest in
+              compiles_in_window=counter.n, backlog=backlog,
+              kv_in_use=kv_used / kv_held if kv_held else None)
+    run.longest_steps = [(d, window_time(t0), *rest) for d, t0, *rest in
                          sorted(s for s in steps if s[1] >= clock0)[-5:]]
     run.ctx["run"] = run
     # -- latency from due time; what never came counts as waited -------
-    rel = {rid: [t - clock0 for t in s] for rid, s in stamps.items()}
+    rel = {rid: [window_time(t) for t in s] for rid, s in stamps.items()}
     if not closed:
         due_in = [o for o in offered if o.due < seconds]
         run.attempted = len(due_in)
@@ -334,8 +364,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     numbers = {"tokens_compared": sum(len(f.tokens) for f in picked)}
     if picked and not run.failed:
         t0 = time.perf_counter()
-        ref = check.reference_for(c, program.reference_weights(params, cfg),
-                                  cell.bench_dir)
+        ref = check.reference_for(
+            c, program.reference_weights(params, cfg, c, cell.bench_dir),
+            cell.bench_dir)
         g = check.gaps(ref, picked, max_len)
         run.ctx.update(reference=ref, picked=picked, length=max_len, gaps=g)
         numbers.update(check.gap_numbers(g))
@@ -345,15 +376,21 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     run.correct, run.checks = check.verdict(numbers, cell.limits["checks"])
 
     if tracer and tracer.state == "done":
-        _trace_context(run, tracer, c, device)
+        _trace_context(run, tracer, cell, device)
     return run
 
 
-def _trace_context(run: Run, tracer: _Tracer, c: dict, device) -> None:
+def call_work(cell: spec.Cell) -> counts.Work:
+    """The work of the cell's served calls, as its family counts it."""
+    return check.family(cell.config, cell.bench_dir).work(cell.config)
+
+
+def _trace_context(run: Run, tracer: _Tracer, cell: spec.Cell,
+                   device) -> None:
     summary = tracer.reduce()
     run.ctx.update({"trace": summary, "counters": tracer.counters,
                     "positions": tracer.positions,
-                    "shape": counts.shape_of(c),
+                    "work": call_work(cell),
                     "peaks": counts.peaks_for(device.device_kind)})
     run.busy_s = summary.busy_s
     run.traced_window_s = summary.window_s

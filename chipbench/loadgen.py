@@ -15,6 +15,11 @@ distribution.
 The Poisson gaps are the arrival process of ``serve.loadgen``'s
 ``poisson_arrivals`` (exponential with mean ``1/rate``), taken as
 quantiles in the same way.
+
+A closed loop whose requests outlast the window starts aged where the
+mix sets ``aged_start`` (:func:`aged`): its first requests enter part
+way through their outputs, so the window holds requests at every
+stage of their lives rather than one batch at its start.
 """
 
 from __future__ import annotations
@@ -26,10 +31,10 @@ from statistics import NormalDist
 import numpy as np
 
 __all__ = ["Request", "quantiles", "stratified_order", "open_loop",
-           "ClosedLoopSource"]
+           "ClosedLoopSource", "aged"]
 
 #: stream ids: one independent numpy stream per quantity and seed
-_PROMPT, _OUTPUT, _GAP, _TOKENS = 1, 2, 3, 4
+_PROMPT, _OUTPUT, _GAP, _TOKENS, _AGE = 1, 2, 3, 4, 5
 
 
 @dataclass
@@ -149,3 +154,24 @@ class ClosedLoopSource:
             self._fill()
         out, self._buf = self._buf[:k], self._buf[k:]
         return out
+
+
+def aged(reqs: list[Request], mix: dict, seed: int, vocab: int
+         ) -> list[Request]:
+    """``reqs`` as requests that have already served part of their
+    outputs: request ``i`` has served ``floor(u_i * max_new_tokens)``
+    tokens, the ``u_i`` the levels ``(j + 0.5) / n`` in an order the
+    mix's ``order_seed`` fixes. The served tokens (ids drawn from the
+    seed) join its prompt, and it asks for the rest; every seed ages
+    the same requests by the same counts."""
+    n = len(reqs)
+    order = np.random.default_rng([int(mix["order_seed"]), _AGE])
+    u = (order.permutation(n) + 0.5) / n
+    tok = _rng(seed, _AGE)
+    out = []
+    for r, ui in zip(reqs, u):
+        a = int(ui * r.max_new_tokens)
+        out.append(Request(r.rid, r.due,
+                           np.concatenate([r.prompt, _tokens(tok, a, vocab)]),
+                           r.max_new_tokens - a))
+    return out

@@ -4,8 +4,17 @@ The program is :mod:`repro`: its ``ModelConfig``, the parameter layout
 that ``repro.models.transformer.init`` defines, and
 ``repro.serve.ServingEngine``. This module maps a configuration file
 (the published ``config.json`` keys, as run) onto them, makes the
-weights from the seed, and gives the reference a plain per-layer view
-of those weights. Nothing here computes a result the check compares.
+weights from the seed, and gives the reference a plain view of those
+weights. Nothing here computes a result the check compares.
+
+What differs between families lives in files of their own, found by
+the configuration's ``model_type``: a family adds its configuration
+(``chipbench/configs/<name>.json``), its program mapping
+(``chipbench/families/<model_type>.py``: ``model_config`` and
+``reference_weights``), and its reference with the work a call needs
+(``chipbench/reference/<model_type>.py``: ``reference`` and
+``work``). This module holds what is true of the
+program for every family.
 """
 
 from __future__ import annotations
@@ -19,20 +28,27 @@ from repro.models import transformer as T
 from repro.models.common import ModelConfig
 from repro.serve import ServingEngine, SchedulerPolicy
 
-__all__ = ["PROGRAM_RMS_EPS", "model_config", "make_params", "build_engine",
-           "reference_weights"]
+from . import spec
+
+__all__ = ["PROGRAM_RMS_EPS", "family", "model_config", "make_params",
+           "build_engine", "reference_weights"]
 
 #: the program's RMSNorm eps (``repro.models.common.rmsnorm``); it has
 #: no option for another, so a configuration must state this one
 PROGRAM_RMS_EPS = 1e-6
 
 
-def model_config(c: dict, name: str) -> ModelConfig:
-    """The program's ``ModelConfig`` for configuration file ``c``.
+def family(c: dict, bench_dir=spec.BENCH_DIR):
+    """The program mapping of the configuration's family,
+    ``<bench_dir>/families/<model_type>.py``."""
+    return spec.load_family("families", c, bench_dir)
 
-    Besides the published ``config.json`` keys, the file states
-    ``qkv_bias``: whether the q, k and v projections carry a bias (Qwen2
-    checkpoints do, and their ``config.json`` has no key for it)."""
+
+def model_config(c: dict, name: str, bench_dir=spec.BENCH_DIR
+                 ) -> ModelConfig:
+    """The program's ``ModelConfig`` for configuration file ``c``, as
+    its family maps it; a configuration that asks for what the program
+    cannot compute is an error."""
     if c["hidden_act"] != "silu":
         raise ValueError(f"{name}: the program's MLP is SwiGLU (silu)")
     if c["rms_norm_eps"] != PROGRAM_RMS_EPS:
@@ -41,23 +57,7 @@ def model_config(c: dict, name: str) -> ModelConfig:
     if c.get("attention_bias"):
         raise ValueError(f"{name}: the program has no bias on the attention "
                          f"output projection")
-    n_exp = int(c.get("num_local_experts", 0))
-    window = c.get("sliding_window")
-    if not c.get("use_sliding_window", True):
-        window = None
-    return ModelConfig(
-        name=name, n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"],
-        head_dim=c.get("head_dim") or
-        c["hidden_size"] // c["num_attention_heads"],
-        d_ff=0 if n_exp else c["intermediate_size"], vocab=c["vocab_size"],
-        qkv_bias=bool(c["qkv_bias"]), sliding_window=window,
-        rope_theta=float(c["rope_theta"]), n_experts=n_exp,
-        top_k=int(c.get("num_experts_per_tok", 0)),
-        moe_d_ff=c["intermediate_size"] if n_exp else 0,
-        tie_embeddings=bool(c["tie_word_embeddings"]),
-        dtype=c["torch_dtype"])
+    return family(c, bench_dir).model_config(c, name)
 
 
 def _leaf_scale(names: list[str], shape: tuple, n_layers: int) -> tuple:
@@ -104,31 +104,8 @@ def build_engine(cfg: ModelConfig, params, max_len: int) -> ServingEngine:
                          policy=SchedulerPolicy())
 
 
-def reference_weights(params, cfg: ModelConfig) -> dict:
-    """A plain view of the program's weights for the reference: arrays
-    named by what they are, ``x @ W`` orientation, every layer's
-    arrays stacked on a leading layer axis; ``head`` is None where the
-    head is the embedding, tied."""
-    if params["prefix"]:
-        raise ValueError("leading dense layers are not mapped")
-    if len(params["stack"]) != 1:
-        raise ValueError("only a one-layer repeating unit is mapped")
-    st = params["stack"][0]
-    mix = st["mixer"]
-    layers = {"attn_norm": st["norm1"]["scale"],
-              "mlp_norm": st["norm2"]["scale"]}
-    for k in ("wq", "wk", "wv", "wo"):
-        layers[k] = mix[k]["w"]
-        if "b" in mix[k]:
-            layers["b" + k[1]] = mix[k]["b"]
-    if "moe" in st:
-        layers["router"] = st["moe"]["router"]["w"]
-        for k in ("w_gate", "w_up", "w_down"):
-            layers["experts_" + k[2:]] = st["moe"]["experts"][k]
-    else:
-        for k in ("w_gate", "w_up", "w_down"):
-            layers[k[2:]] = st["mlp"][k]["w"]
-    head = None if cfg.tie_embeddings else params["lm_head"]["w"]
-    return {"embed": params["embed"]["w"],
-            "final_norm": params["final_norm"]["scale"], "head": head,
-            "layers": layers}
+def reference_weights(params, cfg: ModelConfig, c: dict,
+                      bench_dir=spec.BENCH_DIR) -> dict:
+    """The plain view of the program's weights that the family's
+    reference reads (``chipbench/families/common.py``)."""
+    return family(c, bench_dir).reference_weights(params, cfg)

@@ -6,10 +6,9 @@ Written from the published model descriptions (the Hugging Face
 no kernel: one sequence, every position at once, causal. Imports
 nothing of the program.
 
-Weights come as a plain dict (see ``chipbench.program.reference_
-weights``): ``x @ W`` orientation, the layers stacked on a leading
-axis. They are read in whatever dtype they are stored in and computed
-in float32.
+Weights come as a plain dict (see ``chipbench/families/common.py``):
+``x @ W`` orientation, the layers stacked on a leading axis. They are
+read in whatever dtype they are stored in and computed in float32.
 
 ``control=True`` computes the same mathematics with every matrix
 weight rounded to float8 (e4m3) with one scale per output channel: the
@@ -24,8 +23,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import counts
+
 __all__ = ["HI", "mm", "rmsnorm", "rope", "attention", "swiglu", "weight",
-           "Reference"]
+           "decoder_layer", "Reference", "gqa_work"]
 
 HI = jax.lax.Precision.HIGHEST
 _F8 = jnp.float8_e4m3fn
@@ -99,25 +100,47 @@ def swiglu(x, wg, wu, wd, control: bool):
     return mm(h, weight(wd, control))
 
 
+def decoder_layer(cfg: dict, ffn, attn=attention):
+    """One pre-norm decoder layer, ``layer(lw, x, control)``: ``attn``
+    on the normed residual stream, then the family's feed-forward block
+    ``ffn(cfg, lw, h, control)`` on the normed stream, each added to
+    it."""
+    eps = float(cfg["rms_norm_eps"])
+
+    def layer(lw, x, control):
+        h = rmsnorm(x, lw["attn_norm"], eps)
+        x = x + attn(cfg, lw, h, control)
+        h = rmsnorm(x, lw["mlp_norm"], eps)
+        return x + ffn(cfg, lw, h, control)
+    return layer
+
+
 class Reference:
     """Full-sequence logits of one family, computed layer by layer.
 
-    ``ffn(cfg, lw, h, control)`` is the family's feed-forward block on
-    the normed hidden states ``h`` (P, d); the rest of the decoder
-    layer is shared: pre-norm attention and pre-norm feed-forward, each
-    added to the residual stream."""
+    ``stacks`` lists the decoder's layers in order as ``(key, layer)``:
+    the weights of one stack's layers lie under ``weights[key]`` on a
+    leading layer axis, and ``layer(lw, x, control)`` computes one of
+    them (a family with leading dense layers gives two stacks). A family
+    whose layers are all alike gives only its feed-forward block
+    ``ffn``: one stack ``"layers"`` of :func:`decoder_layer`."""
 
-    def __init__(self, cfg: dict, weights: dict, ffn):
-        self.cfg, self.w, self.ffn = cfg, weights, ffn
-        self.n_layers = int(cfg["num_hidden_layers"])
+    def __init__(self, cfg: dict, weights: dict, ffn=None, stacks=None):
+        self.cfg, self.w = cfg, weights
         eps = float(cfg["rms_norm_eps"])
+        if stacks is None:
+            stacks = [("layers", decoder_layer(cfg, ffn))]
 
-        def layer(layers, i, x, control):
-            lw = jax.tree.map(lambda a: a[i], layers)
-            h = rmsnorm(x, lw["attn_norm"], eps)
-            x = x + attention(cfg, lw, h, control)
-            h = rmsnorm(x, lw["mlp_norm"], eps)
-            return x + ffn(cfg, lw, h, control)
+        def indexed(layer):
+            def run(layers, i, x, control):
+                return layer(jax.tree.map(lambda a: a[i], layers), x,
+                             control)
+            return jax.jit(run, static_argnums=(3,))
+
+        #: (key, jitted ``run(layers, i, x, control)``, number of layers)
+        self.stacks = [(key, indexed(layer),
+                        jax.tree.leaves(weights[key])[0].shape[0])
+                       for key, layer in stacks]
 
         def embed(table, ids, control):
             return weight(table, control, axis=-1)[ids]
@@ -128,7 +151,6 @@ class Reference:
                 return mm(x, weight(table, control, axis=-1).T)
             return mm(x, weight(head_w, control))
 
-        self._layer = jax.jit(layer, static_argnums=(3,))
         self._embed = jax.jit(embed, static_argnums=(2,))
         self._head = jax.jit(head, static_argnums=(4,))
 
@@ -137,7 +159,18 @@ class Reference:
         w = self.w
         with jax.default_matmul_precision("highest"):
             x = self._embed(w["embed"], jnp.asarray(ids, jnp.int32), control)
-            for i in range(self.n_layers):
-                x = self._layer(w["layers"], jnp.int32(i), x, control)
+            for key, layer, n in self.stacks:
+                for i in range(n):
+                    x = layer(w[key], jnp.int32(i), x, control)
             return self._head(w["embed"], w["head"], w["final_norm"], x,
                               control)
+
+
+def gqa_work(c: dict, ffn_params: int) -> counts.Layer:
+    """The work of one layer of this module's :func:`attention` at the
+    configuration's widths, with a feed-forward block of
+    ``ffn_params``."""
+    d, h = c["hidden_size"], c["num_attention_heads"]
+    return counts.gqa_layer(d, h, c["num_key_value_heads"],
+                            c.get("head_dim") or d // h, bool(c["qkv_bias"]),
+                            ffn_params)

@@ -13,6 +13,9 @@ w3 h)``; the output is the weighted sum of the chosen experts. No
 token is ever dropped. Computed densely, one expert at a time over all
 positions, with the weight of an expert a position did not choose set
 to 0, so that one expert's float32 weights are in memory at a time.
+
+Work of a served call (``chipbench.counts``): every layer's attention,
+its router, and the ``k`` experts a token is routed to, not all ``E``.
 """
 
 from __future__ import annotations
@@ -20,7 +23,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from chipbench.reference.common import Reference, mm, swiglu, weight
+from chipbench import counts
+from chipbench.reference.common import Reference, gqa_work, mm, swiglu, \
+    weight
 
 
 def ffn(cfg: dict, lw: dict, h, control: bool):
@@ -44,6 +49,15 @@ def reference(cfg: dict, weights: dict) -> Reference:
     return Reference(cfg, weights, ffn)
 
 
+def work(c: dict) -> counts.Work:
+    d = c["hidden_size"]
+    layer = gqa_work(c, counts.routed_params(
+        d, c["num_local_experts"], c["num_experts_per_tok"],
+        c["intermediate_size"]))
+    return counts.decoder(d, c["vocab_size"],
+                          [layer] * c["num_hidden_layers"])
+
+
 def router_margins(ref: Reference, ids):
     """(layers, P) numpy: at each layer and position, how far the
     weakest chosen expert's routing weight lies above the strongest one
@@ -65,10 +79,11 @@ def router_margins(ref: Reference, ids):
                      -1)[:, ::-1]
         return p[:, k - 1] - p[:, k]
 
+    (key, layer, n), = ref.stacks
     out = []
     with jax.default_matmul_precision("highest"):
         x = ref._embed(w["embed"], jnp.asarray(ids, jnp.int32), False)
-        for i in range(ref.n_layers):
-            out.append(np.asarray(margin(w["layers"], jnp.int32(i), x)))
-            x = ref._layer(w["layers"], jnp.int32(i), x, False)
+        for i in range(n):
+            out.append(np.asarray(margin(w[key], jnp.int32(i), x)))
+            x = layer(w[key], jnp.int32(i), x, False)
     return np.stack(out)

@@ -6,11 +6,15 @@ dense SwiGLU MLP ``down(silu(gate(x)) * up(x))``, residual. Final
 RMSNorm, then the head, tied to the embedding where the configuration
 says so. No departure from the published description is needed here;
 the sliding window is off (``use_sliding_window: false``).
+
+Work of a served call (``chipbench.counts``): every layer's attention,
+q/k/v bias included, and its dense MLP.
 """
 
 from __future__ import annotations
 
-from chipbench.reference.common import Reference, swiglu
+from chipbench import counts
+from chipbench.reference.common import Reference, gqa_work, swiglu
 
 
 def ffn(cfg: dict, lw: dict, h, control: bool):
@@ -19,3 +23,10 @@ def ffn(cfg: dict, lw: dict, h, control: bool):
 
 def reference(cfg: dict, weights: dict) -> Reference:
     return Reference(cfg, weights, ffn)
+
+
+def work(c: dict) -> counts.Work:
+    d = c["hidden_size"]
+    layer = gqa_work(c, counts.swiglu_params(d, c["intermediate_size"]))
+    return counts.decoder(d, c["vocab_size"],
+                          [layer] * c["num_hidden_layers"])

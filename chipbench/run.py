@@ -94,6 +94,9 @@ def main(argv=None) -> int:
     harness._say(f"window {run.window_s:.3f}s, {run.attempted} requests, "
                  f"{run.tokens} tokens, compiles in window "
                  f"{run.compiles_in_window}")
+    if run.kv_in_use is not None:
+        harness._say(f"KV cache in use: {100 * run.kv_in_use:.2f}% of the "
+                     f"positions the live requests' caches reserve")
     harness._say("longest steps (s, at s, live, calls, compose s, "
                  "execute s): " + str([tuple(round(x, 4) for x in st)
                                        for st in run.longest_steps]))

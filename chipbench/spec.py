@@ -8,14 +8,19 @@ name:
   ``BENCHMARK.json`` names)
 * traffic mix ``<t>``:       ``chipbench/traffic/<t>.json``
 * per-layer metric ``<m>``:  ``chipbench/metrics/<m before the first dot>.py``
-* reference family:          ``chipbench/reference/<model_type>.py``
 * correctness limit:         ``chipbench/limits/<workload>.json``
+* the configuration's family, by its ``model_type``:
+  ``chipbench/families/<model_type>.py`` (``model_config`` and
+  ``reference_weights``: the configuration's keys and the program's
+  weights mapped onto the program and the reference) and
+  ``chipbench/reference/<model_type>.py`` (``reference``: the plain
+  float32 model; ``work``: what one served call needs, in the terms of
+  ``chipbench/counts.py``)
 
-What differs between families is data: a configuration states its
-shapes and flags (``qkv_bias`` among them), and its family's reference
-file holds the feed-forward block. A new cell, mix, configuration,
+So a family adds three files: its configuration, its program mapping,
+and its reference with its counts. A new cell, mix, configuration,
 family or metric is new files and new entries in ``BENCHMARK.json``;
-nothing here changes.
+nothing here changes, and no shared file names a family's keys.
 """
 
 from __future__ import annotations
@@ -25,11 +30,15 @@ import json
 from dataclasses import dataclass
 from pathlib import Path
 
-__all__ = ["BENCH_DIR", "ROOT", "Cell", "load_benchmark", "load_cell",
-           "metric_reader_path", "load_module"]
+__all__ = ["BENCH_DIR", "ROOT", "FAMILY_KINDS", "Cell", "load_benchmark",
+           "load_cell", "family_path", "load_family", "metric_reader_path",
+           "load_module"]
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
+
+#: the directories that hold a file of each family, by ``model_type``
+FAMILY_KINDS = ("families", "reference")
 
 
 @dataclass(frozen=True)
@@ -68,6 +77,8 @@ def load_cell(name: str, root: Path = ROOT, bench: dict | None = None,
     centry = configs[w["config"]]
     with open(root / centry["file"]) as f:
         config = json.load(f)
+    for kind in FAMILY_KINDS:
+        family_path(kind, config, bench_dir)
     with open(bench_dir / "traffic" / f"{w['traffic']}.json") as f:
         traffic = json.load(f)
     with open(bench_dir / "limits" / f"{name}.json") as f:
@@ -77,6 +88,23 @@ def load_cell(name: str, root: Path = ROOT, bench: dict | None = None,
                 end_to_end=_for_cell(bench["end_to_end"], name),
                 per_layer=_for_cell(bench["per_layer"], name),
                 limits=limits, bench_dir=bench_dir)
+
+
+def family_path(kind: str, config: dict, bench_dir: Path = BENCH_DIR
+                ) -> Path:
+    """``<bench_dir>/<kind>/<model_type>.py``; a family with no such
+    file is an error that names the path."""
+    path = bench_dir / kind / f"{config['model_type']}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"model_type {config['model_type']!r} has no {kind} file: "
+            f"{path} is missing")
+    return path
+
+
+def load_family(kind: str, config: dict, bench_dir: Path = BENCH_DIR):
+    """The family module of ``kind`` for the configuration."""
+    return load_module(family_path(kind, config, bench_dir))
 
 
 def metric_reader_path(metric: str, bench_dir: Path = BENCH_DIR) -> Path:

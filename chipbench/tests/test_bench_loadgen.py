@@ -81,7 +81,8 @@ def test_closed_loop_epochs_keep_their_totals():
     assert [r.rid for r in a] == list(range(40))
 
 
-@pytest.mark.parametrize("name", ["short-chat-open", "long-prompt-closed"])
+@pytest.mark.parametrize("name", ["short-chat-open", "long-prompt-closed",
+                                  "short-prompt-long-output-closed"])
 def test_mix_keeps_its_source_statistics(name):
     """The stratified set keeps the statistic the mix's source publishes
     (a mean or a median) within 2%, and every cut the mix lists under
@@ -98,3 +99,32 @@ def test_mix_keeps_its_source_statistics(name):
     for path, cut in mix["reduced"].items():
         group, stat = path.split(".")
         assert mix[group][stat] == cut["run"] != cut["published"]
+    for r in loadgen.ClosedLoopSource(mix, 1, 1000).take(256) \
+            if mix["loop"] == "closed" else []:
+        assert len(r.prompt) + r.max_new_tokens <= mix["max_len"]
+
+
+@pytest.mark.parametrize("seed", [5, 2 ** 31 + 9])
+def test_aged_start_serves_the_rest_of_each_output(seed):
+    """Each first request keeps its prompt, takes its share of the
+    output as served tokens (the shares one per stratum, the same for
+    every seed), and asks for the rest; its whole length is unchanged."""
+    mix = _mix("short-prompt-long-output-closed")
+    first = loadgen.ClosedLoopSource(mix, seed, 1000).take(32)
+    aged = loadgen.aged(first, mix, seed, 1000)
+    served = []
+    for r, a in zip(first, aged):
+        n = len(a.prompt) - len(r.prompt)
+        assert a.rid == r.rid and np.array_equal(a.prompt[:len(r.prompt)],
+                                                 r.prompt)
+        assert 0 <= n < r.max_new_tokens and a.max_new_tokens == \
+            r.max_new_tokens - n
+        assert all(0 <= t < 1000 for t in a.prompt)
+        served.append(n / r.max_new_tokens)
+    strata = sorted(int(u * 32) for u in served)
+    assert len(set(strata)) >= 28 and max(served) > 0.9
+    other = loadgen.aged(loadgen.ClosedLoopSource(mix, seed + 1, 1000)
+                         .take(32), mix, seed + 1, 1000)
+    assert [(len(a.prompt), a.max_new_tokens) for a in aged] == \
+        [(len(a.prompt), a.max_new_tokens) for a in other]
+    assert _key(aged) == _key(loadgen.aged(first, mix, seed, 1000))

@@ -19,7 +19,7 @@ def _setup(family, seed=3):
     cfg = program.model_config(c, family).replace(dtype="float32",
                                                   capacity_factor=8.0)
     params = program.make_params(cfg, seed, dtype=jnp.float32)
-    ref = check.reference_for(c, program.reference_weights(params, cfg))
+    ref = check.reference_for(c, program.reference_weights(params, cfg, c))
     ids = np.random.default_rng(seed).integers(0, cfg.vocab, 24)
     return c, cfg, params, ref, ids
 
@@ -52,7 +52,7 @@ def test_reference_sees_qkv_bias_and_router():
     for family, key in (("qwen2", "bq"), ("mixtral", "router")):
         c, cfg, params, ref, ids = _setup(family)
         base = np.asarray(ref.logits(ids))
-        w = program.reference_weights(params, cfg)
+        w = program.reference_weights(params, cfg, c)
         w["layers"][key] = w["layers"][key] * 3.0 + 0.5
         moved = np.asarray(check.reference_for(c, w).logits(ids))
         assert np.max(np.abs(moved - base)) > 1e-2, family

@@ -20,8 +20,8 @@ def test_every_name_resolves_to_its_file():
         assert (spec.ROOT / c["file"]).is_file(), c["name"]
         with open(spec.ROOT / c["file"]) as f:
             conf = json.load(f)
-        assert (spec.BENCH_DIR / "reference" /
-                f"{conf['model_type']}.py").is_file()
+        for kind in spec.FAMILY_KINDS:
+            assert spec.family_path(kind, conf).is_file()
         assert c["file"].startswith(bench["paths"][0] + "/")
     for w in bench["workloads"]:
         cell = spec.load_cell(w["name"])
@@ -36,12 +36,13 @@ def test_every_name_resolves_to_its_file():
 
 def test_new_config_mix_and_metric_need_only_new_files(tmp_path):
     """A configuration of a family the benchmark does not run yet (a
-    Llama-style dense model: no bias, untied head), its reference, a
-    new mix and a new per-layer metric, added as files beside copies of
-    the existing ones, run through the harness with no change to any
-    existing file."""
+    Llama-style dense model: no bias, untied head), its program mapping
+    and reference, a new mix and a new per-layer metric, added as files
+    beside copies of the existing ones, run through the harness with no
+    change to any existing file."""
     bdir = tmp_path / "chipbench"
-    for sub in ("configs", "traffic", "limits", "metrics", "reference"):
+    for sub in ("configs", "traffic", "limits", "metrics", "reference",
+                "families"):
         shutil.copytree(spec.BENCH_DIR / sub, bdir / sub,
                         ignore=shutil.ignore_patterns("__pycache__"))
     bench = spec.load_benchmark()
@@ -49,6 +50,7 @@ def test_new_config_mix_and_metric_need_only_new_files(tmp_path):
 
     (bdir / "configs" / "llama-tiny.json").write_text(
         json.dumps(smoke.llama_config()))
+    (bdir / "families" / "llama.py").write_text(smoke.LLAMA_FAMILY)
     (bdir / "reference" / "llama.py").write_text(smoke.LLAMA_REFERENCE)
     (bdir / "traffic" / "tiny-open.json").write_text(
         json.dumps(smoke.open_mix()))
