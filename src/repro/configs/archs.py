@@ -63,6 +63,18 @@ def _mixtral_8x7b_smoke() -> ModelConfig:
         n_experts=4, top_k=2, moe_d_ff=96, sliding_window=64)
 
 
+#: DeepSeek-V2's published YaRN ``rope_scaling`` (its config.json)
+_DEEPSEEK_V2_YARN = {"type": "yarn", "factor": 40,
+                     "original_max_position_embeddings": 4096,
+                     "beta_fast": 32, "beta_slow": 1, "mscale": 0.707,
+                     "mscale_all_dim": 0.707}
+
+#: DeepSeek-V2's published routing: group-limited greedy top-k over 8
+#: groups, 3 kept; gates not renormalised, times 16
+_DEEPSEEK_V2_ROUTING = dict(n_group=8, topk_group=3, norm_topk_prob=False,
+                            routed_scaling_factor=16.0)
+
+
 def _deepseek_v2_236b() -> ModelConfig:
     return ModelConfig(
         name="deepseek-v2-236b", n_layers=60, d_model=5120, n_heads=128,
@@ -70,17 +82,20 @@ def _deepseek_v2_236b() -> ModelConfig:
         attn_type="mla", kv_lora_rank=512, q_lora_rank=1536,
         qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
         n_experts=160, top_k=6, n_shared_experts=2, moe_d_ff=1536,
-        first_dense_layers=1)
+        first_dense_layers=1, rope_scaling=_DEEPSEEK_V2_YARN,
+        **_DEEPSEEK_V2_ROUTING)
 
 
 def _deepseek_v2_236b_smoke() -> ModelConfig:
+    # 8 experts in the published 8 groups: one expert a group
     return ModelConfig(
         name="deepseek-v2-236b-smoke", n_layers=3, d_model=128, n_heads=8,
         n_kv_heads=8, head_dim=16, d_ff=256, vocab=512,
         attn_type="mla", kv_lora_rank=64, q_lora_rank=48,
         qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
         n_experts=8, top_k=2, n_shared_experts=1, moe_d_ff=64,
-        first_dense_layers=1)
+        first_dense_layers=1, rope_scaling=_DEEPSEEK_V2_YARN,
+        **_DEEPSEEK_V2_ROUTING)
 
 
 def _qwen15_05b() -> ModelConfig:

@@ -196,13 +196,16 @@ def _ffn_params(cfg: ModelConfig, i: int, *, active: bool) -> float:
     """Parameter bytes-relevant count of layer ``i``'s ffn/moe stage.
 
     ``active=True`` counts only routed-active experts (the decode-time
-    weight stream); ``active=False`` counts the full expert bank (the
-    prefill case, where a long chunk touches every expert)."""
+    weight stream: of a token's ``top_k``, the share that lands on the
+    experts held here); ``active=False`` counts the held expert bank
+    (the prefill case, where a long chunk touches every expert).  The
+    shared experts and the router over all experts count either way."""
     d = cfg.d_model
     if cfg.is_moe_layer(i) and cfg.n_experts:
         per_expert = 3.0 * d * cfg.moe_d_ff
-        n_live = (cfg.top_k + cfg.n_shared_experts if active
-                  else cfg.n_experts + cfg.n_shared_experts)
+        routed = (cfg.top_k * cfg.n_held / cfg.n_experts if active
+                  else cfg.n_held)
+        n_live = routed + cfg.n_shared_experts
         return float(n_live * per_expert + d * cfg.n_experts)
     if cfg.d_ff <= 0:
         return 0.0

@@ -21,7 +21,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .common import ModelConfig, PyTree, apply_rope, dense, make_dense
+from .common import (ModelConfig, PyTree, apply_rope, dense, make_dense,
+                     yarn_mscale)
 
 __all__ = ["GQA", "MLA", "sdpa", "decode_sdpa", "causal_mask_bias"]
 
@@ -249,7 +250,8 @@ class GQA:
         B = x.shape[0]
         q, k, v = GQA._qkv(p, cfg, x)
         if cfg.use_rope:
-            cos, sin = rope_tables(pos[None], cfg.head_dim, cfg.rope_theta)
+            cos, sin = rope_tables(pos[None], cfg.head_dim, cfg.rope_theta,
+                                   cfg.rope_scaling)
             q = apply_rope(q, cos[None], sin[None])
             k = apply_rope(k, cos[None], sin[None])
         slots = cache["k"].shape[1]
@@ -281,7 +283,21 @@ class MLA:
     Cache stores only ``c_kv`` (kv_lora_rank) and the shared rope key
     (qk_rope_head_dim) per token.  Decode uses the *absorbed* form so
     the compressed cache is attended to directly.
+
+    The rope channels of q and k are rotated in halves (pair ``i`` is
+    channels ``i`` and ``i + dr/2``); the published checkpoints pair
+    adjacent channels, which is the same model with those projection
+    columns permuted.  Under YaRN ``rope_scaling`` the softmax scale
+    is ``mscale(factor, mscale_all_dim)**2 / sqrt(dn + dr)``.
     """
+
+    @staticmethod
+    def softmax_scale(cfg: ModelConfig) -> float:
+        scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+        s = dict(cfg.rope_scaling or ())
+        if s.get("mscale_all_dim"):
+            scale *= yarn_mscale(s["factor"], s["mscale_all_dim"]) ** 2
+        return scale
 
     @staticmethod
     def init(key, cfg: ModelConfig) -> PyTree:
@@ -342,7 +358,7 @@ class MLA:
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope, (B, S, H, dr))], axis=-1)
-        scale = 1.0 / math.sqrt(dn + dr)
+        scale = MLA.softmax_scale(cfg)
         if S > 2048:
             out = blockwise_sdpa(q, k, v, scale=scale, causal=True,
                                  window=None)
@@ -370,7 +386,8 @@ class MLA:
         r_kv = cfg.kv_lora_rank
         q_nope, q_rope = MLA._q(p, cfg, x)          # (B,1,H,dn),(B,1,H,dr)
         c_kv, k_rope = MLA._ckv(p, cfg, x)          # (B,1,r_kv),(B,1,dr)
-        cos, sin = rope_tables(pos[None], dr, cfg.rope_theta)
+        cos, sin = rope_tables(pos[None], dr, cfg.rope_theta,
+                               cfg.rope_scaling)
         q_rope = apply_rope(q_rope, cos[None], sin[None])
         k_rope = apply_rope(k_rope[:, :, None, :], cos[None], sin[None])[:, :, 0]
         ck = jax.lax.dynamic_update_slice_in_dim(
@@ -380,20 +397,23 @@ class MLA:
         T = ck.shape[1]
         valid = jnp.broadcast_to(jnp.arange(T) <= pos, (B, T))
         # Absorb W_uk into the query: q_c = q_nope @ W_uk^T  (per head).
-        w_uk = p["w_uk"]["w"].reshape(r_kv, H, dn)
-        q_c = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0],
-                         w_uk.astype(q_nope.dtype))        # (B,H,r_kv)
-        logits = jnp.einsum("bhr,btr->bht", q_c, ck,
-                            preferred_element_type=jnp.float32)
-        logits = logits + jnp.einsum(
-            "bhd,btd->bht", q_rope[:, 0], cr,
-            preferred_element_type=jnp.float32)
-        logits = logits / math.sqrt(dn + dr)
-        logits = jnp.where(valid[:, None, :], logits, _NEG_INF)
-        w = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-        ctx = jnp.einsum("bht,btr->bhr", w.astype(ck.dtype), ck)  # (B,H,r_kv)
+        with jax.named_scope("mla.absorb_q"):
+            w_uk = p["w_uk"]["w"].reshape(r_kv, H, dn)
+            q_c = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0],
+                             w_uk.astype(q_nope.dtype))    # (B,H,r_kv)
+        with jax.named_scope("mla.latent_scores"):
+            logits = jnp.einsum("bhr,btr->bht", q_c, ck,
+                                preferred_element_type=jnp.float32)
+            logits = logits + jnp.einsum(
+                "bhd,btd->bht", q_rope[:, 0], cr,
+                preferred_element_type=jnp.float32)
+            logits = logits * MLA.softmax_scale(cfg)
+            logits = jnp.where(valid[:, None, :], logits, _NEG_INF)
+            w = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
         # Absorb W_uv on the way out.
-        w_uv = p["w_uv"]["w"].reshape(r_kv, H, dv)
-        out = jnp.einsum("bhr,rhd->bhd", ctx, w_uv.astype(ctx.dtype))
+        with jax.named_scope("mla.absorb_v"):
+            ctx = jnp.einsum("bht,btr->bhr", w.astype(ck.dtype), ck)
+            w_uv = p["w_uv"]["w"].reshape(r_kv, H, dv)
+            out = jnp.einsum("bhr,rhd->bhd", ctx, w_uv.astype(ctx.dtype))
         y = dense(p["wo"], out.reshape(B, 1, -1).astype(x.dtype))
         return y, {"c_kv": ck, "k_rope": cr}

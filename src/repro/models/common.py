@@ -15,10 +15,11 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = ["ModelConfig", "Dense", "rmsnorm", "layernorm", "norm",
            "init_norm", "act_fn", "rope_tables", "apply_rope",
-           "make_dense", "dense", "PyTree"]
+           "yarn_mscale", "yarn_inv_freq", "make_dense", "dense", "PyTree"]
 
 PyTree = Any
 
@@ -43,6 +44,9 @@ class ModelConfig:
     sliding_window: int | None = None
     rope_theta: float = 10_000.0
     use_rope: bool = True
+    #: None, or the published ``rope_scaling`` dict (``type`` "yarn");
+    #: held as its sorted items, so that the config stays hashable
+    rope_scaling: Any = None
 
     # --- MLA (deepseek-v2) ---
     kv_lora_rank: int = 0
@@ -59,6 +63,14 @@ class ModelConfig:
     moe_layer_period: int = 1        # MoE every k-th layer
     first_dense_layers: int = 0      # leading dense layers (deepseek)
     capacity_factor: float = 1.25
+    n_group: int = 0                 # routing groups; 0: no grouping
+    topk_group: int = 0              # groups a token may route into
+    norm_topk_prob: bool = True      # renormalise the top-k gates
+    routed_scaling_factor: float = 1.0
+    #: the routed experts this layer holds: ``experts_held`` of the
+    #: router's ``n_experts`` from ``expert_offset`` on (0: all of them)
+    experts_held: int = 0
+    expert_offset: int = 0
 
     # --- block pattern, cycled over layers ---
     block_pattern: tuple[str, ...] = ("attn",)   # attn|mamba|mlstm|slstm
@@ -80,7 +92,22 @@ class ModelConfig:
     input_mode: str = "tokens"       # "tokens" | "embeddings" (stub frontend)
     logit_softcap: float = 0.0
 
+    def __post_init__(self):
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
+
     # ------------------------------------------------------------------
+    @property
+    def n_held(self) -> int:
+        """Routed experts whose matrices this layer holds."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def expert_share(self) -> bool:
+        """Whether the layer holds only a share of the router's experts."""
+        return self.n_held < self.n_experts
+
     @property
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
@@ -171,13 +198,52 @@ def act_fn(kind: str):
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope_tables(positions: jnp.ndarray, head_dim: int,
-                theta: float) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """cos/sin tables for the given positions; shape (..., head_dim//2)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude factor for a context stretched ``factor`` times
+    (``yarn_get_mscale`` of DeepSeek-V2's modelling code)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(head_dim: int, theta: float, scaling) -> np.ndarray:
+    """(head_dim//2,) rotary frequencies under YaRN: pairs that turn
+    fewer than ``beta_slow`` times over the original context are
+    divided by ``factor``, pairs that turn more than ``beta_fast``
+    times are kept, with a linear ramp between."""
+    s = dict(scaling)
+    f, orig = float(s["factor"]), s["original_max_position_embeddings"]
+
+    def dim_of(rotations):
+        return head_dim * math.log(orig / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(dim_of(s.get("beta_fast", 32))), 0)
+    high = min(math.ceil(dim_of(s.get("beta_slow", 1))), head_dim - 1)
+    if low == high:
+        high += 0.001
     half = head_dim // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    extra = 1.0 / theta ** (np.arange(0, head_dim, 2) / head_dim)
+    ramp = np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
+    return extra / f * ramp + extra * (1.0 - ramp)
+
+
+def rope_tables(positions: jnp.ndarray, head_dim: int, theta: float,
+                scaling=None) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """cos/sin tables for the given positions; shape (..., head_dim//2).
+    ``scaling``: a YaRN ``rope_scaling`` (its frequencies, and cos/sin
+    times ``mscale(f, mscale) / mscale(f, mscale_all_dim)``)."""
+    half = head_dim // 2
+    if scaling is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+        ang = positions.astype(jnp.float32)[..., None] * freqs
+        return jnp.cos(ang), jnp.sin(ang)
+    s = dict(scaling)
+    if s.get("type", s.get("rope_type")) != "yarn":
+        raise ValueError(f"rope_scaling {s}: only yarn is implemented")
+    freqs = jnp.asarray(yarn_inv_freq(head_dim, theta, s), jnp.float32)
     ang = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.cos(ang), jnp.sin(ang)
+    m = yarn_mscale(s["factor"], s.get("mscale", 1)) / yarn_mscale(
+        s["factor"], s.get("mscale_all_dim", 0))
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
 
 
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray,

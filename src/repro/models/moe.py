@@ -27,6 +27,20 @@ nothing.  On that range an expert receives at most ``T`` tokens; where
 ``E <= 9K``: Mixtral's 8 experts, top-2, give ``T <= 3``) the grouped
 path drops nothing either, and both compute the same sums.
 Training-size inputs and the distributed paths stay grouped.
+
+Routing follows the configuration: a softmax over the router's ``E``
+experts, optionally limited to the ``topk_group`` best of ``n_group``
+groups (a group scores its best expert; DeepSeek-V2's
+``group_limited_greedy``), the top ``K`` kept, renormalised or not
+(``norm_topk_prob``) and times ``routed_scaling_factor``.
+
+A layer may hold only a share of the experts (``experts_held`` from
+``expert_offset``; expert parallelism with this chip's exchange left
+out): it routes over all ``E`` and adds only its held experts' part.
+An assignment to an absent expert adds nothing and reads no weights:
+the gathered path runs each held assignment in a ``lax.cond`` branch,
+and the grouped buffer has rows for the held experts only.  The local
+path compares ``T·K`` with the held count.
 """
 
 from __future__ import annotations
@@ -53,26 +67,52 @@ def _expert_ffn(p: PyTree, x: jnp.ndarray, act: str) -> jnp.ndarray:
     return jnp.einsum("ecf,efd->ecd", h, wd)
 
 
+def _expert_weights(p: PyTree, e) -> PyTree:
+    """Expert ``e``'s matrices, a dynamic slice of each stack; of layer
+    ``p["layer"]`` where the stacks hold every layer's experts
+    (``transformer._experts_apart``)."""
+    names = ("w_gate", "w_up", "w_down")
+    if "layer" not in p:
+        return {n: jax.lax.dynamic_index_in_dim(p[n], e) for n in names}
+    return {n: jax.lax.dynamic_index_in_dim(
+        jax.lax.dynamic_index_in_dim(p[n], p["layer"], keepdims=False), e)
+        for n in names}
+
+
 def _gathered_ffn(p: PyTree, xt: jnp.ndarray, expert_ids: jnp.ndarray,
-                  gates: jnp.ndarray, act: str) -> jnp.ndarray:
+                  gates: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     """Routed experts of each token, read one by one: xt (T, d),
     expert_ids and gates (T, K) -> (T, d).
 
     Each expert's matrices are a dynamic slice at its scalar id, which
     XLA fuses into the matmul that reads it, so only the T·K routed
     experts' weights are streamed (a gather along the expert axis
-    would copy them first).  The loop is unrolled: T·K < E bounds it.
-    Outputs are combined as the grouped path does: each in ``xt.dtype``,
-    times its gate in ``xt.dtype``, summed token-major in k order."""
+    would copy them first).  The loop is unrolled: T·K < held bounds
+    it.  Outputs are combined as the grouped path does: each in
+    ``xt.dtype``, times its gate in ``xt.dtype``, summed token-major in
+    k order.  Where the layer holds a share of the experts, each
+    assignment runs in a ``lax.cond`` on whether its expert is held:
+    an absent one adds zeros and reads nothing."""
     T, K = expert_ids.shape
     g = gates.astype(xt.dtype)
+
+    def routed(p, x, e, gate):
+        y = _expert_ffn(_expert_weights(p, e), x[None], cfg.act)[0, 0]
+        return y * gate
+
     rows = []
     for t in range(T):
         row = None
         for k in range(K):
-            w = {n: jax.lax.dynamic_index_in_dim(p[n], expert_ids[t, k])
-                 for n in ("w_gate", "w_up", "w_down")}
-            y = _expert_ffn(w, xt[None, t:t + 1], act)[0, 0] * g[t, k]
+            e = expert_ids[t, k]
+            if cfg.expert_share:
+                e = e - cfg.expert_offset
+                y = jax.lax.cond(
+                    (e >= 0) & (e < cfg.n_held), routed,
+                    lambda p, x, e, gate: jnp.zeros(x.shape[1:], x.dtype),
+                    p, xt[t:t + 1], e, g[t, k])
+            else:
+                y = routed(p, xt[t:t + 1], e, g[t, k])
             row = y if row is None else row + y
         rows.append(row)
     return jnp.stack(rows)
@@ -81,12 +121,13 @@ def _gathered_ffn(p: PyTree, xt: jnp.ndarray, expert_ids: jnp.ndarray,
 class MoE:
     @staticmethod
     def init(key, cfg: ModelConfig) -> PyTree:
-        d, E, ff = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
+        """The router over all ``n_experts``; the held experts' stacks."""
+        d, E, ff = cfg.d_model, cfg.n_held, cfg.moe_d_ff
         ks = iter(jax.random.split(key, 8))
         s_in = 1.0 / math.sqrt(d)
         s_out = 1.0 / math.sqrt(ff * 2 * cfg.n_layers)
         p = {
-            "router": make_dense(next(ks), d, E, scale=s_in),
+            "router": make_dense(next(ks), d, cfg.n_experts, scale=s_in),
             "experts": {
                 "w_gate": jax.random.normal(next(ks), (E, d, ff)) * s_in,
                 "w_up": jax.random.normal(next(ks), (E, d, ff)) * s_in,
@@ -112,8 +153,8 @@ class MoE:
     def local_path(cfg: ModelConfig, n_tokens: int) -> str:
         """The local path ``n_tokens`` tokens take: ``"gathered"`` while
         their ``T·K`` assignments name fewer expert matrices than the
-        grouped buffer's ``E``, else ``"grouped"``."""
-        if n_tokens * cfg.top_k < cfg.n_experts:
+        grouped buffer's held experts, else ``"grouped"``."""
+        if n_tokens * cfg.top_k < cfg.n_held:
             return "gathered"
         return "grouped"
 
@@ -145,39 +186,49 @@ class MoE:
     def _fwd_local(p: PyTree, cfg: ModelConfig, x: jnp.ndarray
                    ) -> tuple[jnp.ndarray, dict]:
         B, S, d = x.shape
-        E, K = cfg.n_experts, cfg.top_k
+        K, off = cfg.top_k, cfg.expert_offset
         T = B * S
         xt = x.reshape(T, d)
 
         if MoE.local_path(cfg, T) == "gathered":
             logits, probs, gate_vals, flat_e = MoE._route(p, cfg, xt)
-            y = _gathered_ffn(p["experts"], xt, flat_e.reshape(T, K),
-                              gate_vals, cfg.act)
+            with jax.named_scope("moe.held_experts"):
+                y = _gathered_ffn(p["experts"], xt, flat_e.reshape(T, K),
+                                  gate_vals, cfg)
             keep = jnp.ones(flat_e.shape, bool)
         else:
             C = MoE.capacity(cfg, T)
             logits, probs, gate_vals, flat_e, ranks, keep = \
                 MoE._route_local(p, cfg, xt, C)
-            slot = flat_e * C + jnp.where(keep, ranks, 0)      # (T*K,)
-            token_idx = jnp.repeat(jnp.arange(T), K)
-            # Scatter tokens into the (E*C, d) buffer (dropped -> slot 0
-            # masked).
-            buf = jnp.zeros((E * C, d), x.dtype)
-            contrib = jnp.where(keep[:, None], xt[token_idx], 0.0)
-            buf = buf.at[slot].add(contrib, mode="drop")
-            buf = buf.reshape(E, C, d)
+            with jax.named_scope("moe.held_experts"):
+                experts = p["experts"]
+                if "layer" in experts:
+                    experts = {n: a[experts["layer"]]
+                               for n, a in experts.items() if n != "layer"}
+                local = flat_e - off
+                # an absent expert's assignments are left out as dropped
+                use = keep & (local >= 0) & (local < cfg.n_held)
+                slot = jnp.where(use, local * C + ranks, 0)    # (T*K,)
+                token_idx = jnp.repeat(jnp.arange(T), K)
+                # Scatter tokens into the (held*C, d) buffer (left out
+                # -> slot 0 masked).
+                buf = jnp.zeros((cfg.n_held * C, d), x.dtype)
+                contrib = jnp.where(use[:, None], xt[token_idx], 0.0)
+                buf = buf.at[slot].add(contrib, mode="drop")
+                buf = buf.reshape(cfg.n_held, C, d)
 
-            y_buf = _expert_ffn(p["experts"], buf, cfg.act)    # (E, C, d)
+                y_buf = _expert_ffn(experts, buf, cfg.act)      # (held, C, d)
 
-            # Combine: gather each kept assignment's output and weight
-            # by gate.
-            y_flat = y_buf.reshape(E * C, d)[slot]             # (T*K, d)
-            w = jnp.where(keep, gate_vals.reshape(-1), 0.0).astype(x.dtype)
-            y = jnp.zeros((T, d), x.dtype).at[token_idx].add(
-                y_flat * w[:, None])
+                # Combine: gather each used assignment's output and
+                # weight by gate.
+                y_flat = y_buf.reshape(cfg.n_held * C, d)[slot]  # (T*K, d)
+                w = jnp.where(use, gate_vals.reshape(-1), 0.0).astype(x.dtype)
+                y = jnp.zeros((T, d), x.dtype).at[token_idx].add(
+                    y_flat * w[:, None])
 
         if "shared" in p:
-            y = y + MoE._shared_tp(p, cfg, xt, None)
+            with jax.named_scope("moe.shared"):
+                y = y + MoE._shared_tp(p, cfg, xt, None)
         aux = MoE._aux_of(cfg, logits, probs, flat_e, keep, ())
         return y.reshape(B, S, d), aux
 
@@ -188,12 +239,24 @@ class MoE:
 
     @staticmethod
     def _route(p, cfg, xt):
-        """Router in f32: logits and probs (t, E), the renormalised
-        top-k gates (t, K) and their expert ids, flattened (t*K,)."""
+        """Router in f32: logits and probs (t, E), the top-k gates
+        (t, K) and their expert ids, flattened (t*K,)."""
         logits = xt.astype(jnp.float32) @ p["router"]["w"].astype(jnp.float32)
         probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, expert_ids = jax.lax.top_k(probs, cfg.top_k)
-        gate_vals = gate_vals / jnp.sum(gate_vals, -1, keepdims=True)
+        scores = probs
+        if cfg.n_group:
+            with jax.named_scope("moe.route_group_limited"):
+                t, G = probs.shape[0], cfg.n_group
+                grouped = probs.reshape(t, G, cfg.n_experts // G)
+                _, best = jax.lax.top_k(jnp.max(grouped, -1), cfg.topk_group)
+                kept = jnp.sum(jax.nn.one_hot(best, G, dtype=jnp.int32), 1)
+                scores = jnp.where(kept[:, :, None] > 0, grouped,
+                                   0.0).reshape(t, -1)
+        gate_vals, expert_ids = jax.lax.top_k(scores, cfg.top_k)
+        if cfg.norm_topk_prob:
+            gate_vals = gate_vals / jnp.sum(gate_vals, -1, keepdims=True)
+        if cfg.routed_scaling_factor != 1.0:
+            gate_vals = gate_vals * cfg.routed_scaling_factor
         return logits, probs, gate_vals, expert_ids.reshape(-1)
 
     @staticmethod
@@ -249,10 +312,21 @@ class MoE:
         return jax.lax.psum(y, tp_axis) if tp_axis else y
 
     @staticmethod
+    def _whole_bank(cfg: ModelConfig) -> None:
+        """The distributed paths shard the whole bank themselves."""
+        if cfg.expert_share:
+            raise NotImplementedError(
+                f"{cfg.name}: a layer holding {cfg.n_held} of "
+                f"{cfg.n_experts} experts runs on one chip, without a "
+                f"mesh; expert parallelism over such layers is not "
+                f"implemented")
+
+    @staticmethod
     def _fwd_ep(p: PyTree, cfg: ModelConfig, x: jnp.ndarray
                 ) -> tuple[jnp.ndarray, dict]:
         """Expert parallelism: tokens split over (dp, tp); fixed-capacity
         all_to_all dispatch to expert shards; reverse combine."""
+        MoE._whole_bank(cfg)
         from jax.sharding import PartitionSpec as P
         from repro.dist import context as dctx
 
@@ -344,6 +418,7 @@ class MoE:
                 ) -> tuple[jnp.ndarray, dict]:
         """Experts too few to shard: replicate routing, shard every
         expert's d_ff over the model axis, psum the combined output."""
+        MoE._whole_bank(cfg)
         from jax.sharding import PartitionSpec as P
         from repro.dist import context as dctx
 

@@ -197,7 +197,7 @@ def _head(params: PyTree, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
 
 def _rope_for(cfg: ModelConfig, positions: jnp.ndarray):
     dim = cfg.qk_rope_head_dim if cfg.attn_type == "mla" else cfg.head_dim
-    return rope_tables(positions, dim, cfg.rope_theta)
+    return rope_tables(positions, dim, cfg.rope_theta, cfg.rope_scaling)
 
 
 def forward(params: PyTree, cfg: ModelConfig, batch, *,
@@ -362,6 +362,31 @@ def _decode_layer(p: PyTree, cfg: ModelConfig, i: int, x: jnp.ndarray,
     return x, c
 
 
+def _experts_apart(stack: list) -> tuple[list, list]:
+    """The stacked units without their routed experts' matrices, and
+    those matrices (None for a unit without experts).
+
+    A layer that holds a share of the experts runs each held expert in
+    a branch (``MoE._gathered_ffn``); a branch's operand sliced out of
+    the layer stack is copied whole, every held expert of the layer.
+    So the share's layers get the stacks over all layers and their
+    layer index (:func:`_with_experts`), and each branch reads one
+    expert's matrices by (layer, expert)."""
+    rest, experts = [], []
+    for unit in stack:
+        moe = dict(unit.get("moe", {}))
+        experts.append(moe.pop("experts", None))
+        rest.append({**unit, "moe": moe} if "moe" in unit else unit)
+    return rest, experts
+
+
+def _with_experts(unit: PyTree, experts, layer) -> PyTree:
+    if experts is None:
+        return unit
+    return {**unit, "moe": {**unit["moe"],
+                            "experts": {**experts, "layer": layer}}}
+
+
 def decode_step(params: PyTree, cfg: ModelConfig, tok, cache: PyTree,
                 pos, *, unroll: bool = False
                 ) -> tuple[jnp.ndarray, PyTree]:
@@ -386,16 +411,19 @@ def decode_step(params: PyTree, cfg: ModelConfig, tok, cache: PyTree,
         new_prefix.append(c)
 
     reps = (cfg.n_layers - prefix) // period if period else 0
+    stack, experts = params["stack"], None
+    if cfg.expert_share:
+        stack, experts = _experts_apart(stack)
 
     if unroll and reps:
-        new_stack_cols = [jax.tree.map(lambda a: [], params["stack"][u])
-                          for u in range(period)]
         new_stack = []
         per_rep = []
         for r in range(reps):
             rep_cache = []
             for u in range(period):
-                up = jax.tree.map(lambda a, r=r: a[r], params["stack"][u])
+                up = jax.tree.map(lambda a, r=r: a[r], stack[u])
+                if cfg.expert_share:
+                    up = _with_experts(up, experts[u], r)
                 uc = jax.tree.map(lambda a, r=r: a[r], cache["stack"][u])
                 x, c = _decode_layer(up, cfg, prefix + u, x, uc, pos)
                 x = constrain(x, ("dp", None, None))
@@ -409,7 +437,10 @@ def decode_step(params: PyTree, cfg: ModelConfig, tok, cache: PyTree,
         return logits[:, 0], {"prefix": new_prefix, "stack": new_stack}
 
     def scan_body(x, inp):
-        unit_params, unit_cache = inp
+        unit_params, unit_cache = inp[:2]
+        if cfg.expert_share:
+            unit_params = [_with_experts(up, ex, inp[2])
+                           for up, ex in zip(unit_params, experts)]
         new_cache = []
         for u in range(period):
             x, c = _decode_layer(unit_params[u], cfg, prefix + u, x,
@@ -419,8 +450,10 @@ def decode_step(params: PyTree, cfg: ModelConfig, tok, cache: PyTree,
         return x, tuple(new_cache)
 
     if (cfg.n_layers - prefix) > 0:
-        x, new_stack = jax.lax.scan(
-            scan_body, x, (tuple(params["stack"]), tuple(cache["stack"])))
+        xs = (tuple(stack), tuple(cache["stack"]))
+        if cfg.expert_share:
+            xs += (jnp.arange(reps),)
+        x, new_stack = jax.lax.scan(scan_body, x, xs)
     else:
         new_stack = ()
     logits = _head(params, cfg, x)

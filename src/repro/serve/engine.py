@@ -321,6 +321,10 @@ class ServingEngine:
         self._n_moe = T.n_moe_layers(cfg)
         self._moe = (m.counter("moe_dispatch", path=MoE.local_path(cfg, 1))
                      if self._n_moe else None)
+        if self._n_moe:
+            # the experts a MoE layer holds here, of the router's width
+            m.gauge("moe_experts_held").set(cfg.n_held)
+            m.gauge("moe_router_experts").set(cfg.n_experts)
         self._tokens = m.counter("tokens_emitted")
 
     # -- workload characterisation -------------------------------------
@@ -390,7 +394,8 @@ class ServingEngine:
         time (correctness-first prefill).  Returns the last prompt
         token's logits, (1, vocab), and the filled cache."""
         toks = jnp.asarray(prompt, jnp.int32)[None, :]
-        cache = T.init_cache(self.cfg, 1, self.max_len)
+        cache = T.init_cache(self.cfg, 1, self.max_len,
+                             self.cfg.compute_dtype)
         for s in range(toks.shape[1]):
             logits, cache = self._call("prefill", toks[:, s], cache, s)
         return logits, cache

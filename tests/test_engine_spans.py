@@ -6,6 +6,7 @@ the sum of its spans; the counters count what was run; and served
 tokens do not depend on whether the profiler runs."""
 
 import os
+import re
 from collections import defaultdict
 
 import jax
@@ -220,3 +221,49 @@ def test_moe_dispatch_counts_each_calls_moe_layers(arch, path, n_moe):
         assert counted == {}
     else:
         assert counted == {f"moe_dispatch{{path={path}}}": calls * n_moe}
+
+
+def _deepseek_toy():
+    """DeepSeek-V2's smoke model cut to one chip's share as its
+    benchmark configuration is: a leading dense layer and 4 MoE layers,
+    a router over 40 experts in 8 groups of 5, top-4, group 0 held."""
+    return get_config("deepseek-v2-236b", "smoke").replace(
+        n_layers=5, n_experts=40, experts_held=5, top_k=4, moe_d_ff=32)
+
+
+MLA_MOE_SCOPES = ("mla.absorb_q", "mla.latent_scores", "mla.absorb_v",
+                  "moe.route_group_limited", "moe.held_experts",
+                  "moe.shared")
+
+
+def test_decode_program_names_its_latent_attention_and_experts():
+    """The device ops of the served decode step carry the named scopes
+    of the absorbed attention and of the held share's MoE layer."""
+    cfg = _deepseek_toy()
+    params = jax.eval_shape(lambda: T.init(jax.random.PRNGKey(0), cfg))
+    cache = jax.eval_shape(lambda: T.init_cache(cfg, 1, 16))
+    hlo = jax.jit(T.decode_step, static_argnums=(1,)).lower(
+        params, cfg, np.zeros(1, np.int32), cache, 3).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]+)"', hlo))
+    for scope in MLA_MOE_SCOPES:
+        assert any(f"/{scope}/" in n for n in names), scope
+
+
+def test_held_share_gauges_and_dispatch_count():
+    """The engine states the experts a layer holds and the router's
+    width once, and counts each call's 4 MoE layers under the gathered
+    path a one-token call of the held share takes."""
+    cfg = _deepseek_toy()
+    params = jax.jit(T.init, static_argnums=(1,))(jax.random.PRNGKey(0), cfg)
+    eng = ServingEngine(cfg, params, max_len=16)
+    prompt = np.random.default_rng(5).integers(0, cfg.vocab, size=3)
+    eng.submit([Request(0, prompt, max_new_tokens=3)])
+    while eng.step():
+        pass
+    snap = eng.metrics.snapshot()
+    assert snap["moe_experts_held"] == 5
+    assert snap["moe_router_experts"] == 40
+    calls = snap["decode_calls{kind=prefill}"] + \
+        snap["decode_calls{kind=decode}"]
+    assert calls == 3 + 2
+    assert snap["moe_dispatch{path=gathered}"] == 4 * calls

@@ -343,3 +343,19 @@ def test_dag_greedy_n512_sweep():
     assert g.is_topological(sched.order)
     assert _round_names(greedy_order_dag(ks, GTX580)) == \
         _round_names(greedy_order_fast(ks, GTX580))
+
+
+def test_ffn_params_price_the_held_share():
+    """A decode's stream counts the routed experts a token is expected
+    to reach among those held here, the shared experts and the router;
+    a layer holding every expert prices its ``top_k`` as before."""
+    from repro.configs import get_config
+    from repro.graph.kernel_graph import _ffn_params
+    full = get_config("deepseek-v2-236b")
+    share = full.replace(experts_held=20)
+    expert, router = 3.0 * 5120 * 1536, 5120 * 160
+    assert _ffn_params(full, 1, active=True) == (6 + 2) * expert + router
+    assert _ffn_params(share, 1, active=True) == \
+        (6 * 20 / 160 + 2) * expert + router
+    assert _ffn_params(share, 1, active=False) == (20 + 2) * expert + router
+    assert _ffn_params(share, 0, active=True) == 3.0 * 5120 * 12288

@@ -54,3 +54,59 @@ def test_gathered_path_matches_grouped(n_tokens, shared, dtype,
         assert float(aux[k]) == pytest.approx(float(aux_ref[k]), rel=1e-6)
     assert float(aux["moe_drop_frac"]) == 0.0
     assert float(aux_ref["moe_drop_frac"]) == pytest.approx(0.0, abs=1e-6)
+
+
+def _prims(cfg, p, x) -> list[str]:
+    """Primitives of the local MoE's top-level jaxpr."""
+    jaxpr = jax.make_jaxpr(lambda p, x: MoE._fwd_local(p, cfg, x))(p, x)
+    return [e.primitive.name for e in jaxpr.jaxpr.eqns]
+
+
+def _share_cfg(offset: int) -> ModelConfig:
+    """A router over 16 experts in 4 groups of 4, top-3 from the best 2
+    groups, holding the group at ``offset``; gates as DeepSeek-V2's."""
+    return _cfg(1, "float32").replace(
+        n_experts=16, top_k=3, experts_held=4, expert_offset=offset,
+        n_group=4, topk_group=2, norm_topk_prob=False,
+        routed_scaling_factor=16.0)
+
+
+def test_whole_bank_gathered_path_has_no_branch():
+    cfg = _cfg(1, "float32")
+    assert not cfg.expert_share
+    p = MoE.init(jax.random.PRNGKey(0), cfg)
+    assert "cond" not in _prims(cfg, p, jnp.ones((1, 1, cfg.d_model)))
+
+
+@pytest.mark.parametrize("offset", [0, 12])
+def test_held_share_branches_each_assignment(offset, monkeypatch):
+    """A one-token call of a held share runs each of its K assignments
+    in a branch that reads an expert only where it is held, and gives
+    what the grouped buffer of the held experts gives."""
+    cfg = _share_cfg(offset)
+    p = MoE.init(jax.random.PRNGKey(1), cfg)
+    assert p["experts"]["w_gate"].shape == (4, cfg.d_model, cfg.moe_d_ff)
+    assert p["router"]["w"].shape == (cfg.d_model, 16)
+    # a token drawn to the held group's first expert, so that it is
+    # routed there and to others, held or not
+    x = 4.0 * p["router"]["w"][None, None, :, offset] + jax.random.normal(
+        jax.random.PRNGKey(offset), (1, 1, cfg.d_model))
+    assert MoE.local_path(cfg, 1) == "gathered"
+    assert _prims(cfg, p, x).count("cond") == cfg.top_k
+
+    fwd = jax.jit(MoE._fwd_local, static_argnums=1)
+    y, _ = fwd(p, cfg, x)
+    without = MoE._shared_tp(p, cfg, x[0], None)
+    assert float(jnp.abs(y[0] - without).max()) > 0.1     # a held expert ran
+    monkeypatch.setattr(MoE, "local_path",
+                        staticmethod(lambda cfg, n: "grouped"))
+    y_ref, _ = jax.jit(MoE._fwd_local, static_argnums=1)(p, cfg, x)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=0,
+                               atol=1e-6 * float(np.abs(y_ref).max()))
+
+
+def test_held_share_refuses_a_mesh_path():
+    cfg = _share_cfg(0)
+    for path in (MoE._fwd_ep, MoE._fwd_tp):
+        with pytest.raises(NotImplementedError, match="holding 4 of 16"):
+            path({}, cfg, jnp.ones((1, 1, cfg.d_model)))
