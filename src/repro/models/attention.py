@@ -6,7 +6,14 @@ Every variant exposes:
 * ``init(key, cfg) -> params``
 * ``fwd(params, cfg, x, cos, sin) -> y``                (full-sequence)
 * ``init_cache(cfg, batch, max_len, dtype) -> cache``
-* ``decode(params, cfg, x, cache, pos) -> (y, cache)``  (one new token)
+* ``decode(params, cfg, x, cache, pos) -> (y, rows)``   (one new token)
+
+``decode`` reads its cache and never writes it: ``rows`` holds, for
+each cache leaf, the new token's row ``(B, 1, ...)`` in the cache's
+dtype, which the caller writes at ``pos % slots`` on the position axis
+(``repro.models.transformer.decode_step``).  Attention reads the old
+cache with that row selected in at its slot, the same values an
+updated cache would hold.
 
 The scaled-dot-product core is pluggable (``impl='xla' | 'pallas'``) so
 the Pallas TPU kernels in :mod:`repro.kernels` can be swapped in on
@@ -27,6 +34,15 @@ from .common import (ModelConfig, PyTree, apply_rope, dense, make_dense,
 __all__ = ["GQA", "MLA", "sdpa", "decode_sdpa", "causal_mask_bias"]
 
 _NEG_INF = -1e30
+
+
+def _with_row(cache: jnp.ndarray, row: jnp.ndarray,
+              slot: jnp.ndarray) -> jnp.ndarray:
+    """``cache`` (B, T, ...) as it reads with ``row`` (B, 1, ...)
+    written at ``slot``: a select the scores' reads fuse, so the
+    cache is not copied to update one row."""
+    idx = jnp.arange(cache.shape[1]).reshape((1, -1) + (1,) * (cache.ndim - 2))
+    return jnp.where(idx == slot, row, cache)
 
 
 # ---------------------------------------------------------------------------
@@ -256,10 +272,10 @@ class GQA:
             k = apply_rope(k, cos[None], sin[None])
         slots = cache["k"].shape[1]
         slot = pos % slots
-        ck = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k.astype(cache["k"].dtype), slot, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v.astype(cache["v"].dtype), slot, axis=1)
+        k = k.astype(cache["k"].dtype)
+        v = v.astype(cache["v"].dtype)
+        ck = _with_row(cache["k"], k, slot)
+        cv = _with_row(cache["v"], v, slot)
         idx = jnp.arange(slots)
         if cfg.sliding_window is not None and slots == cfg.sliding_window:
             valid = (idx <= slot) | (pos >= slots)  # ring buffer fully warm
@@ -270,7 +286,7 @@ class GQA:
         scale = 1.0 / math.sqrt(cfg.head_dim)
         out = decode_sdpa(q[:, 0], ck, cv, valid, scale=scale)
         y = dense(p["wo"], out.reshape(B, 1, -1).astype(x.dtype))
-        return y, {"k": ck, "v": cv}
+        return y, {"k": k, "v": v}
 
 
 # ---------------------------------------------------------------------------
@@ -390,11 +406,11 @@ class MLA:
                                cfg.rope_scaling)
         q_rope = apply_rope(q_rope, cos[None], sin[None])
         k_rope = apply_rope(k_rope[:, :, None, :], cos[None], sin[None])[:, :, 0]
-        ck = jax.lax.dynamic_update_slice_in_dim(
-            cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), pos, axis=1)
-        cr = jax.lax.dynamic_update_slice_in_dim(
-            cache["k_rope"], k_rope.astype(cache["k_rope"].dtype), pos, axis=1)
-        T = ck.shape[1]
+        c_kv = c_kv.astype(cache["c_kv"].dtype)
+        k_rope = k_rope.astype(cache["k_rope"].dtype)
+        T = cache["c_kv"].shape[1]
+        ck = _with_row(cache["c_kv"], c_kv, pos % T)
+        cr = _with_row(cache["k_rope"], k_rope, pos % T)
         valid = jnp.broadcast_to(jnp.arange(T) <= pos, (B, T))
         # Absorb W_uk into the query: q_c = q_nope @ W_uk^T  (per head).
         with jax.named_scope("mla.absorb_q"):
@@ -416,4 +432,4 @@ class MLA:
             w_uv = p["w_uv"]["w"].reshape(r_kv, H, dv)
             out = jnp.einsum("bhr,rhd->bhd", ctx, w_uv.astype(ctx.dtype))
         y = dense(p["wo"], out.reshape(B, 1, -1).astype(x.dtype))
-        return y, {"c_kv": ck, "k_rope": cr}
+        return y, {"c_kv": c_kv, "k_rope": k_rope}

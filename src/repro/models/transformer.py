@@ -345,8 +345,24 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     return cache
 
 
+def _new_cache(cfg: ModelConfig, i: int, cache: PyTree, out: PyTree,
+               pos, axis: int) -> PyTree:
+    """Layer ``i``'s cache after a call whose mixer returned ``out``.
+    An attention layer's cache is positional and ``out`` holds the new
+    token's rows: each leaf's row is written at ``pos % slots`` on the
+    position ``axis``, once.  A recurrent state is replaced by ``out``."""
+    if cfg.layer_kind(i) != "attn":
+        return out
+    return jax.tree.map(
+        lambda c, r: jax.lax.dynamic_update_slice_in_dim(
+            c, r, pos % c.shape[axis], axis=axis), cache, out)
+
+
 def _decode_layer(p: PyTree, cfg: ModelConfig, i: int, x: jnp.ndarray,
                   c: PyTree, pos) -> tuple[jnp.ndarray, PyTree]:
+    """One layer at one position: the residual stream and what its
+    mixer's ``decode`` returned (rows or a new state, :func:`_new_cache`).
+    ``c`` is only read."""
     kind = cfg.layer_kind(i)
     h = norm(p["norm1"], x, cfg.norm)
     cls = _attn_cls(cfg) if kind == "attn" else _MIXERS[kind]
@@ -396,7 +412,12 @@ def decode_step(params: PyTree, cfg: ModelConfig, tok, cache: PyTree,
     ``unroll=True`` replaces the layer scan with a python loop: the
     per-token HLO is tiny, and unrolling lets resident (serve-mode)
     weights be consumed in place instead of being copied into the
-    scan's stacked layout — see EXPERIMENTS.md §Perf."""
+    scan's stacked layout — see EXPERIMENTS.md §Perf.
+
+    Either way the layers read the cache and return only their new
+    rows (a recurrent layer its new state), and each cache leaf is
+    written once after the layers, one row per layer: a layer's cache
+    passed through the scan's outputs would be copied whole each call."""
     from repro.dist.context import constrain
     prefix, period = unit_period(cfg)
     if cfg.input_mode == "tokens":
@@ -407,8 +428,9 @@ def decode_step(params: PyTree, cfg: ModelConfig, tok, cache: PyTree,
     pos = jnp.asarray(pos, jnp.int32)
     new_prefix = []
     for i, lp in enumerate(params["prefix"]):
-        x, c = _decode_layer(lp, cfg, i, x, cache["prefix"][i], pos)
-        new_prefix.append(c)
+        x, out = _decode_layer(lp, cfg, i, x, cache["prefix"][i], pos)
+        new_prefix.append(_new_cache(cfg, i, cache["prefix"][i], out, pos,
+                                     axis=1))
 
     reps = (cfg.n_layers - prefix) // period if period else 0
     stack, experts = params["stack"], None
@@ -419,20 +441,21 @@ def decode_step(params: PyTree, cfg: ModelConfig, tok, cache: PyTree,
         new_stack = []
         per_rep = []
         for r in range(reps):
-            rep_cache = []
+            rep_out = []
             for u in range(period):
                 up = jax.tree.map(lambda a, r=r: a[r], stack[u])
                 if cfg.expert_share:
                     up = _with_experts(up, experts[u], r)
                 uc = jax.tree.map(lambda a, r=r: a[r], cache["stack"][u])
-                x, c = _decode_layer(up, cfg, prefix + u, x, uc, pos)
+                x, out = _decode_layer(up, cfg, prefix + u, x, uc, pos)
                 x = constrain(x, ("dp", None, None))
-                rep_cache.append(c)
-            per_rep.append(rep_cache)
+                rep_out.append(out)
+            per_rep.append(rep_out)
         for u in range(period):
-            new_stack.append(jax.tree.map(
-                lambda *leaves: jnp.stack(leaves),
-                *[per_rep[r][u] for r in range(reps)]))
+            outs = jax.tree.map(lambda *leaves: jnp.stack(leaves),
+                                *[per_rep[r][u] for r in range(reps)])
+            new_stack.append(_new_cache(cfg, prefix + u, cache["stack"][u],
+                                        outs, pos, axis=2))
         logits = _head(params, cfg, x)
         return logits[:, 0], {"prefix": new_prefix, "stack": new_stack}
 
@@ -441,23 +464,24 @@ def decode_step(params: PyTree, cfg: ModelConfig, tok, cache: PyTree,
         if cfg.expert_share:
             unit_params = [_with_experts(up, ex, inp[2])
                            for up, ex in zip(unit_params, experts)]
-        new_cache = []
+        outs = []
         for u in range(period):
-            x, c = _decode_layer(unit_params[u], cfg, prefix + u, x,
-                                 unit_cache[u], pos)
+            x, out = _decode_layer(unit_params[u], cfg, prefix + u, x,
+                                   unit_cache[u], pos)
             x = constrain(x, ("dp", None, None))
-            new_cache.append(c)
-        return x, tuple(new_cache)
+            outs.append(out)
+        return x, tuple(outs)
 
+    new_stack = []
     if (cfg.n_layers - prefix) > 0:
         xs = (tuple(stack), tuple(cache["stack"]))
         if cfg.expert_share:
             xs += (jnp.arange(reps),)
-        x, new_stack = jax.lax.scan(scan_body, x, xs)
-    else:
-        new_stack = ()
+        x, outs = jax.lax.scan(scan_body, x, xs)
+        new_stack = [_new_cache(cfg, prefix + u, cache["stack"][u], outs[u],
+                                pos, axis=2) for u in range(period)]
     logits = _head(params, cfg, x)
-    return logits[:, 0], {"prefix": new_prefix, "stack": list(new_stack)}
+    return logits[:, 0], {"prefix": new_prefix, "stack": new_stack}
 
 
 def prefill(params: PyTree, cfg: ModelConfig, batch, max_len: int,

@@ -1,16 +1,17 @@
 """Ahead-of-time compiles for a described TPU v5e at qwen1.5-0.5b
 widths: the Pallas kernels of the serving path and the full-width
-decode step; and one MoE layer at mixtral-8x7b widths, whose
-one-token call must read only its routed experts.  No chip is needed,
-and nothing runs: the TPU compiler refuses what the chip would refuse
-(misaligned blocks, unsupported primitives, programs that do not fit
-its memory).
+decode step, which must not copy its KV cache; and one MoE layer at
+mixtral-8x7b widths, whose one-token call must read only its routed
+experts.  No chip is needed, and nothing runs: the TPU compiler
+refuses what the chip would refuse (misaligned blocks, unsupported
+primitives, programs that do not fit its memory).
 
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -84,22 +85,39 @@ def test_kernel_compiles_for_v5e(one_chip, qwen, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_decode_step_fits_one_v5e(one_chip, qwen):
+def _compiled_decode_step(sharding, cfg, max_len):
     def place(tree):
-        return jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+        return jax.tree.map(lambda a: _spec(a.shape, a.dtype, sharding),
                             tree)
 
-    params = place(jax.eval_shape(lambda k: T.init(k, qwen),
+    params = place(jax.eval_shape(lambda k: T.init(k, cfg),
                                   jax.random.PRNGKey(0)))
-    cache = place(jax.eval_shape(lambda: T.init_cache(qwen, 1, 256)))
-    tok = _spec((1,), jnp.int32, one_chip)
-    pos = _spec((), jnp.int32, one_chip)
-    compiled = jax.jit(T.decode_step, static_argnums=(1,)).lower(
-        params, qwen, tok, cache, pos).compile()
-    mem = compiled.memory_analysis()
+    cache = place(jax.eval_shape(lambda: T.init_cache(cfg, 1, max_len)))
+    tok = _spec((1,), jnp.int32, sharding)
+    pos = _spec((), jnp.int32, sharding)
+    return jax.jit(T.decode_step, static_argnums=(1,)).lower(
+        params, cfg, tok, cache, pos).compile()
+
+
+def test_decode_step_fits_one_v5e(one_chip, qwen):
+    mem = _compiled_decode_step(one_chip, qwen, 256).memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < total < _HBM_BYTES, total
+
+
+def test_decode_step_copies_no_cache_slice(one_chip, qwen):
+    """A call writes one K/V row per layer: no ``copy`` whose result
+    has the cache's length remains (a layer's cache passed through the
+    layer scan would be copied in and out, four slices a layer)."""
+    max_len = 1104
+    text = _compiled_decode_step(one_chip, qwen, max_len).as_text()
+    ops = re.findall(r"(%\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(", text)
+    long = [(name, op) for name, dims, op in ops
+            if str(max_len) in dims.split(",")]
+    assert long, "no instruction of the cache's length parsed"
+    copies = [name for name, op in long if op == "copy"]
+    assert not copies, copies
 
 
 def test_one_token_moe_reads_only_the_routed_experts(one_chip, monkeypatch):
