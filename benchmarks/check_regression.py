@@ -8,20 +8,16 @@ this machine and diffs them against the committed
 second-scale cells don't flap on scheduler/runner jitter — on a
 shared single-core box the sub-second refine cells swing several
 hundred ms run-to-run, so the absolute slack, not the ratio, is what
-keeps them stable; a genuinely devectorized batched path is caught by
-the load-insensitive ``--batched-floor`` throughput ratio instead)
-fails the check with exit code 1.  The event-refine delta cells are compared the same
+keeps them stable) fails the check with exit code 1.  The event-refine delta cells are compared the same
 way (their wall time is the event-model refinement hot path).  Both
 sides use best-of-``--repeats`` wall times (the committed JSON records
 its own ``repeats``), the standard protocol for wall-clock guards.
 
-Two absolute floors ride along (both load-insensitive ratios of two
-fresh runs on the same box, so they need no committed baseline): the
-batched-vs-sequential refine throughput ratio (``--batched-floor``)
-and, since PR 7, the incremental-vs-batch compose-time speedup under
-serving churn (``--churn-floor``, re-running
-``benchmarks/serving.py``'s ``churn_compose_bench`` at its largest
-``n_live`` cell).  The ``repro.serve`` re-export surface is also
+An absolute floor rides along (a load-insensitive ratio of two fresh
+runs on the same box, so it needs no committed baseline): the
+incremental-vs-batch compose-time speedup under serving churn
+(``--churn-floor``, re-running ``benchmarks/serving.py``'s
+``churn_compose_bench`` at its largest ``n_live`` cell).  The ``repro.serve`` re-export surface is also
 import-checked, so the PR 7 package split can't silently drop the
 historical flat names.
 
@@ -50,25 +46,16 @@ import scaling  # noqa: E402
 #: ``slice_fast`` the lazy slice-aware greedy,
 #: repro.slice.greedy_order_slices; ``dag_refine_gated`` the gated
 #: delta-refinement path, repro.graph.delta.GatedDeltaEvaluator via
-#: refine_order_dag(model="gated"); ``event_batched`` /
-#: ``dag_refine_gated_batched`` the vectorized candidate evaluator,
-#: repro.core.batched.refine_order_batched)
+#: refine_order_dag(model="gated"))
 _GUARDED_PATHS = ("fast", "event_delta", "dag_fast", "slice_fast",
-                  "dag_refine_gated", "event_batched",
-                  "dag_refine_gated_batched")
-
-#: floor on the fresh run's batched-vs-sequential effective-move
-#: throughput ratio at n >= 512 (the committed JSON records >= 3x;
-#: the guard is deliberately looser so shared-runner noise doesn't
-#: flap it, while still catching a devectorized batched path)
-_BATCHED_FLOOR = 2.0
+                  "dag_refine_gated")
 
 #: floor on the fresh incremental-vs-batch compose-time speedup at the
 #: churn benchmark's largest n_live cell (benchmarks/serving.py,
 #: ``churn_compose_bench``; the committed BENCH_serving.json records
-#: >= 2x at 64 live requests — same looser-than-committed discipline
-#: as the batched floor, catching a live path that degenerated into
-#: rebuild-every-step without flapping on runner noise)
+#: >= 2x at 64 live requests; the guard is deliberately looser, so it
+#: catches a live path that degenerated into rebuild-every-step
+#: without flapping on runner noise)
 _CHURN_FLOOR = 1.6
 
 #: ceiling on the tracing-enabled / tracing-disabled wall-time ratio
@@ -367,10 +354,6 @@ def main(argv=None) -> int:
     ap.add_argument("--repeats", type=int, default=None,
                     help="best-of-k for the fresh run (default: the "
                          "committed JSON's own repeats)")
-    ap.add_argument("--batched-floor", type=float,
-                    default=_BATCHED_FLOOR,
-                    help="minimum batched/sequential effective-move "
-                         "throughput ratio at n >= 512 (0 disables)")
     ap.add_argument("--churn-floor", type=float, default=_CHURN_FLOOR,
                     help="minimum incremental/batch compose-time "
                          "speedup at the churn benchmark's largest "
@@ -425,12 +408,6 @@ def main(argv=None) -> int:
             json.dump(fresh, f, indent=2)
     regressions = compare(committed, fresh, args.threshold,
                           args.abs_slack)
-    if args.batched_floor > 0:
-        ratio = fresh["summary"].get("min_batched_event_ratio_at_512plus")
-        if ratio is not None and ratio < args.batched_floor:
-            regressions.append(
-                f"batched event-refine throughput ratio at n>=512: "
-                f"{ratio:.2f}x < floor {args.batched_floor:.2f}x")
     regressions += _surface_regressions()
     if args.churn_floor > 0:
         import serving

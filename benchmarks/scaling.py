@@ -29,16 +29,6 @@ DAGs: the checkpointing gated delta path
 candidate (``DagEventSimulator`` as ``time_fn``, path
 ``dag_refine_gated_full``, skipped above ``--max-gated-full-n``).
 
-**Batched refinement** sections (ISSUE 6) measure the vectorized
-candidate evaluator (``repro.core.batched.refine_order_batched``
-behind the ``batch_size=`` knob): path ``event_batched`` over n in
-{256 .. 4096} against the sequential ``event_delta`` cells at the
-shared ns (the ISSUE-6 bar is >= 3x effective-move throughput at
-n >= 512), path ``dag_refine_gated_batched`` over the gated band,
-and an ``arch_gated_quality`` pin — batched gated refinement is
-never worse than sequential on the three traced-arch workloads
-(4-core serving slice).
-
 Emits ``BENCH_scheduler_scaling.json`` for the perf trajectory
 (consumed by ``benchmarks/check_regression.py``).  The reference
 construction path is O(R * n^2) Python-level ScoreGen reruns and is
@@ -79,18 +69,6 @@ EVENT_NS = (64, 128, 256, 512, 1024)
 #: each gated full sim walks the whole dependency frontier, so the
 #: full-re-sim baseline is capped separately (--max-gated-full-n).
 GATED_NS = (64, 128, 256, 512)
-#: batched refine (ISSUE 6): the vectorized candidate evaluator
-#: (``repro.core.batched.refine_order_batched`` behind the
-#: ``batch_size=`` knob) scores whole ``(B, n)`` move batches per
-#: pass; measured against the sequential delta path at the shared ns
-#: and batched-only at the 2048/4096 scaling cells (where sequential
-#: evaluation is no longer a reasonable baseline to wait for).
-BATCH_SIZE = 512
-BATCHED_NS = (256, 512, 1024, 2048, 4096)
-#: traced archs for the batched-gated quality pin (same workloads as
-#: benchmarks/dag.py, on the 4-core serving slice where the gated
-#: makespan is genuinely order-sensitive)
-ARCHS = ("qwen1.5-0.5b", "mixtral-8x7b", "deepseek-v2-236b")
 _FAMS = [ep_kernel, bs_kernel, es_kernel, sw_kernel]
 
 
@@ -239,16 +217,6 @@ def gated_refine(ks, edges, device, path: str) -> dict:
         _, t_g, evals = refine_order_dag(
             order, device, edge_ids=eids, time_fn=sim.simulate,
             budget=EVENT_BUDGET, neighborhood="adjacent")
-    elif path == "dag_refine_gated_batched":
-        # rescore=False: this is the *throughput* cell, measured under
-        # the fast contract (quality pinned to the input order).  The
-        # arch_gated_quality cells run the default sequential-parity
-        # contract (rescore on), which trades engine passes for
-        # matching the sequential refiner's makespans.
-        _, t_g, evals = refine_order_dag(
-            order, device, edge_ids=eids, model="gated",
-            budget=EVENT_BUDGET, neighborhood="adjacent",
-            batch_size=BATCH_SIZE, rescore=False)
     else:
         _, t_g, evals = refine_order_dag(
             order, device, edge_ids=eids, model="gated",
@@ -269,10 +237,6 @@ def event_refine(ks, device, path: str) -> dict:
         _, t_ev, evals = refine_order(
             order, device, time_fn=sim.simulate,
             budget=EVENT_BUDGET, neighborhood="adjacent")
-    elif path == "event_batched":
-        _, t_ev, evals = refine_order(
-            order, device, model="event", budget=EVENT_BUDGET,
-            neighborhood="adjacent", batch_size=BATCH_SIZE)
     else:
         _, t_ev, evals = refine_order(
             order, device, model="event", budget=EVENT_BUDGET,
@@ -283,42 +247,8 @@ def event_refine(ks, device, path: str) -> dict:
             "modelled_event_time_s": t_ev}
 
 
-def arch_gated_quality(arch: str) -> dict:
-    """Batched-vs-sequential gated refinement on a traced arch (the
-    4-core serving slice, where the gated makespan is genuinely
-    order-sensitive): the batched path's exact re-verification before
-    acceptance pins its refined makespan to never-worse than its
-    input, and this cell pins it against the *sequential* refiner's
-    result on real workloads (the ISSUE-6 quality bar)."""
-    from repro.configs import get_config
-    from repro.graph import greedy_order_dag, trace_arch
-
-    dev4 = make_serving_device(n_units=4)
-    traced = trace_arch(get_config(arch, "full"), max_stages=16)
-    g = traced.graph
-    eids = g.edges_by_id()
-    order = greedy_order_dag(g.kernels, dev4, edges=g.edges).order
-    t0 = time.perf_counter()
-    _, t_seq, _ = refine_order_dag(
-        order, dev4, edge_ids=eids, model="gated",
-        budget=EVENT_BUDGET, neighborhood="adjacent")
-    wall_seq = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    _, t_bat, _ = refine_order_dag(
-        order, dev4, edge_ids=eids, model="gated",
-        budget=EVENT_BUDGET, neighborhood="adjacent",
-        batch_size=BATCH_SIZE)
-    wall_bat = time.perf_counter() - t0
-    return {"path": "arch_gated_quality", "n": len(g.kernels),
-            "wall_s": wall_bat, "wall_seq_s": wall_seq,
-            "gated_time_sequential_s": t_seq,
-            "gated_time_batched_s": t_bat,
-            "batched_no_worse": t_bat <= t_seq * (1 + 1e-9)}
-
-
 def run(max_ref_n: int = 512, seed: int = 0, max_event_full_n: int = 256,
         max_gated_full_n: int = 128, repeats: int = 2,
-        max_batched_n: int = 1024, arch_quality: bool = False,
         print_fn=print) -> dict:
     results = []
     print_fn("# Scheduler scaling: reference vs vectorized "
@@ -410,63 +340,15 @@ def run(max_ref_n: int = 512, seed: int = 0, max_event_full_n: int = 256,
                      f"{rec['refine_evals']},{rec['moves_per_s']:.1f},"
                      f"{ratio if ratio == '' else f'{ratio:.1f}'}")
             results.append({"scenario": "gpu_dag", "n": n, **rec})
-    print_fn("# Batched event refine (ISSUE 6): vectorized (B, n) "
-             f"candidate batches, batch_size {BATCH_SIZE}; throughput "
-             "ratio vs the sequential event_delta cell at the same n")
-    print_fn("scenario,n,path,wall_s,evals,moves_per_s,"
-             "throughput_ratio_vs_delta")
-    delta_tp = {r["n"]: r["moves_per_s"] for r in results
-                if r["path"] == "event_delta"}
-    for n in BATCHED_NS:
-        if n > max_batched_n:
-            continue
-        rng = random.Random(seed)
-        ks = gpu_mix(rng, n)
-        rec = _best_of(repeats,
-                       lambda: event_refine(ks, GTX580, "event_batched"))
-        ratio = (rec["moves_per_s"] / delta_tp[n]
-                 if n in delta_tp else "")
-        print_fn(f"gpu_mix,{n},{rec['path']},{rec['wall_s']:.4f},"
-                 f"{rec['refine_evals']},{rec['moves_per_s']:.1f},"
-                 f"{ratio if ratio == '' else f'{ratio:.2f}'}")
-        results.append({"scenario": "gpu_mix", "n": n, **rec})
-    print_fn("# Batched gated refine: same chain DAGs as the gated "
-             "delta section")
-    print_fn("scenario,n,path,wall_s,evals,moves_per_s")
-    for n in GATED_NS:
-        if n > max_batched_n:
-            continue
-        rng = random.Random(seed)
-        ks = gpu_mix(rng, n)
-        edges = chain_edges(rng, n, width=max(4, n // 8))
-        rec = _best_of(repeats, lambda: gated_refine(
-            ks, edges, GTX580, "dag_refine_gated_batched"))
-        print_fn(f"gpu_dag,{n},{rec['path']},{rec['wall_s']:.4f},"
-                 f"{rec['refine_evals']},{rec['moves_per_s']:.1f}")
-        results.append({"scenario": "gpu_dag", "n": n, **rec})
-    if arch_quality:
-        print_fn("# Batched gated quality pin on traced archs "
-                 "(4-core serving slice): batched <= sequential")
-        print_fn("workload,n,gated_seq_ms,gated_batched_ms,no_worse")
-        for arch in ARCHS:
-            rec = arch_gated_quality(arch)
-            print_fn(f"arch:{arch},{rec['n']},"
-                     f"{rec['gated_time_sequential_s'] * 1e3:.3f},"
-                     f"{rec['gated_time_batched_s'] * 1e3:.3f},"
-                     f"{rec['batched_no_worse']}")
-            results.append({"scenario": f"arch:{arch}", **rec})
     summary = _summary(results)
     out = {"benchmark": "scheduler_scaling",
            "refine_budget": REFINE_BUDGET,
            "event_refine_budget": EVENT_BUDGET,
            "ns": list(NS), "event_ns": list(EVENT_NS),
            "gated_ns": list(GATED_NS),
-           "batched_ns": list(BATCHED_NS),
-           "batch_size": BATCH_SIZE,
            "max_ref_n": max_ref_n,
            "max_event_full_n": max_event_full_n,
            "max_gated_full_n": max_gated_full_n,
-           "max_batched_n": max_batched_n,
            "repeats": repeats,
            "results": results, "summary": summary}
     print_fn(f"summary: {json.dumps(summary)}")
@@ -504,39 +386,12 @@ def _summary(results: list[dict]) -> dict:
         if d is not None:
             gated_tp[f"{scen}@n={n}"] = (d["moves_per_s"] /
                                          max(r["moves_per_s"], 1e-9))
-    batched_tp = {}
-    for (scen, n, path), r in by.items():
-        if path != "event_delta":
-            continue
-        b = by.get((scen, n, "event_batched"))
-        if b is not None:
-            batched_tp[f"{scen}@n={n}"] = (b["moves_per_s"] /
-                                           max(r["moves_per_s"], 1e-9))
-    tp512plus = [v for k, v in batched_tp.items()
-                 if int(k.rsplit("n=", 1)[1]) >= 512]
-    batched_gated_tp = {}
-    for (scen, n, path), r in by.items():
-        if path != "dag_refine_gated":
-            continue
-        b = by.get((scen, n, "dag_refine_gated_batched"))
-        if b is not None:
-            batched_gated_tp[f"{scen}@n={n}"] = (
-                b["moves_per_s"] / max(r["moves_per_s"], 1e-9))
-    arch_rows = [r for r in results
-                 if r["path"] == "arch_gated_quality"]
     return {"speedups": speedups,
             "min_speedup_at_512": min(s512.values()) if s512 else None,
             "quality_no_worse_than_reference": quality_ok,
             "event_move_throughput_ratios": event_tp,
             "event_delta_throughput_at_256": tp256[0] if tp256 else None,
-            "gated_move_throughput_ratios": gated_tp,
-            "batched_event_throughput_ratios": batched_tp,
-            "min_batched_event_ratio_at_512plus": (
-                min(tp512plus) if tp512plus else None),
-            "batched_gated_throughput_ratios": batched_gated_tp,
-            "batched_arch_quality_ok": (
-                all(r["batched_no_worse"] for r in arch_rows)
-                if arch_rows else None)}
+            "gated_move_throughput_ratios": gated_tp}
 
 
 def main(argv=None) -> int:
@@ -545,13 +400,6 @@ def main(argv=None) -> int:
     ap.add_argument("--max-ref-n", type=int, default=512)
     ap.add_argument("--max-event-full-n", type=int, default=256)
     ap.add_argument("--max-gated-full-n", type=int, default=128)
-    ap.add_argument("--max-batched-n", type=int, default=max(BATCHED_NS),
-                    help="largest n for the batched refine cells "
-                         "(check_regression re-runs only up to its own "
-                         "smaller default)")
-    ap.add_argument("--no-arch-quality", action="store_true",
-                    help="skip the traced-arch batched-vs-sequential "
-                         "gated quality pin")
     ap.add_argument("--full", action="store_true",
                     help="run the reference path at every n")
     ap.add_argument("--seed", type=int, default=0)
@@ -562,9 +410,7 @@ def main(argv=None) -> int:
     out = run(max_ref_n=max_ref, seed=args.seed,
               max_event_full_n=args.max_event_full_n,
               max_gated_full_n=args.max_gated_full_n,
-              repeats=args.repeats,
-              max_batched_n=args.max_batched_n,
-              arch_quality=not args.no_arch_quality)
+              repeats=args.repeats)
     with open(args.out, "w") as f:
         json.dump(out, f, indent=2)
     print(f"wrote {args.out}")

@@ -15,11 +15,7 @@ from .simulator import (EventCheckpoint, EventSimulator, RoundCheckpoint,
 from .experiments import EXPERIMENTS, experiment
 from .fastscore import (ProfileTable, greedy_order_fast, pair_score_matrix,
                         score_matrix_fast, warm_start_insert)
-from .refine import (DeltaEvaluator, DeltaRoundEvaluator, refine_order,
-                     refined_schedule)
-from .batched import (BatchedEventSim, BatchedRoundSim, PackedKernels,
-                      audit_pair_scores, pair_score_matrix_batched,
-                      refine_order_batched)
+from .refine import DeltaEvaluator, refine_order, refined_schedule
 from .tpu import (TpuWorkItem, compose_rounds, decode_profile,
                   make_serving_device, prefill_profile)
 
@@ -35,11 +31,7 @@ __all__ = [
     "EXPERIMENTS", "experiment",
     "ProfileTable", "greedy_order_fast", "pair_score_matrix",
     "score_matrix_fast", "warm_start_insert",
-    "DeltaEvaluator", "DeltaRoundEvaluator", "refine_order",
-    "refined_schedule",
-    "BatchedEventSim", "BatchedRoundSim", "PackedKernels",
-    "audit_pair_scores", "pair_score_matrix_batched",
-    "refine_order_batched",
+    "DeltaEvaluator", "refine_order", "refined_schedule",
     "TpuWorkItem", "compose_rounds", "decode_profile",
     "make_serving_device", "prefill_profile",
 ]

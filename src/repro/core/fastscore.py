@@ -252,20 +252,14 @@ def warm_start_insert(rounds: Sequence[Sequence[KernelProfile]],
 
 
 def greedy_order_fast(kernels: Sequence[KernelProfile],
-                      device: DeviceModel,
-                      table: ProfileTable | None = None) -> Schedule:
+                      device: DeviceModel) -> Schedule:
     """Algorithm 1, incremental: identical schedules to
     ``scheduler.greedy_order`` in ``O(n^2 * D)`` instead of
-    ``O(R * n^2)`` Python-level ScoreGen reruns.
-
-    ``table`` accepts an already-built :class:`ProfileTable` for the
-    same ``(kernels, device)`` so a greedy + refine pipeline
-    (:func:`repro.core.refine.refined_schedule`) packs exactly once."""
+    ``O(R * n^2)`` Python-level ScoreGen reruns."""
     n = len(kernels)
     if n == 0:
         return Schedule([])
-    if table is None:
-        table = ProfileTable.build(kernels, device)
+    table = ProfileTable.build(kernels, device)
     mat = pair_score_matrix(table)
     # Mask the lower triangle and diagonal: pair_score(a, b) and
     # pair_score(b, a) can differ in the last ulp (the residual term's

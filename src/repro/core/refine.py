@@ -84,8 +84,7 @@ from .resources import DeviceModel, KernelProfile
 from .scheduler import Schedule
 from .simulator import EventCheckpoint, RoundCheckpoint, simulate
 
-__all__ = ["refine_order", "refined_schedule", "DeltaEvaluator",
-           "DeltaRoundEvaluator"]
+__all__ = ["refine_order", "refined_schedule", "DeltaEvaluator"]
 
 
 class _FastRoundSim:
@@ -545,16 +544,9 @@ class DeltaEvaluator:
         return self.evaluate_costed(cand, first_changed)[0]
 
     def evaluate_costed(self, cand: Sequence[KernelProfile],
-                        first_changed: int,
-                        trace=None) -> tuple[float, float]:
+                        first_changed: int) -> tuple[float, float]:
         """As :meth:`evaluate`, plus the evaluation's cost as a
-        fraction of a full re-simulation (suffix length / n).
-
-        ``trace`` forwards to the suffix re-simulation (the batched
-        engines' exact verification re-sims attach their recorder
-        here); a checkpoint-resumed suffix only records spans from the
-        resume point on.
-        """
+        fraction of a full re-simulation (suffix length / n)."""
         if self._per_position:
             # One checkpoint per position, captured before any block
             # of that position was dispatched: the checkpoint at
@@ -562,9 +554,8 @@ class DeltaEvaluator:
             if first_changed < len(self._ckpts):
                 cp = self._ckpts[first_changed]
                 frac = (len(cand) - cp.pos) / max(len(cand), 1)
-                return self.sim.simulate(cand, start_state=cp,
-                                         trace=trace)[0], frac
-            return self.sim.simulate(cand, trace=trace)[0], 1.0
+                return self.sim.simulate(cand, start_state=cp)[0], frac
+            return self.sim.simulate(cand)[0], 1.0
         # Round model: only checkpoints strictly before the first
         # changed position are safe — the round preceding a checkpoint
         # at position p closed by examining the kernel at p (failed or
@@ -577,11 +568,11 @@ class DeltaEvaluator:
             else:
                 break
         if best is None:
-            return self.sim.simulate(cand, trace=trace)[0], 1.0
+            return self.sim.simulate(cand)[0], 1.0
         frac = (len(cand) - best.pos) / max(len(cand), 1)
         t = self.sim.simulate(cand, start_pos=best.pos,
                               head_blocks=best.blocks_left,
-                              t0=best.time, trace=trace)[0]
+                              t0=best.time)[0]
         return t, frac
 
     def boundaries(self) -> list[int] | None:
@@ -590,18 +581,6 @@ class DeltaEvaluator:
         if self._per_position:
             return None
         return [cp.pos for cp in self._ckpts]
-
-    def round_boundaries(self) -> list[int]:
-        """Order positions at which the base's rounds open (round
-        model; kept for backward compatibility)."""
-        return [cp.pos for cp in self._ckpts]
-
-
-class DeltaRoundEvaluator(DeltaEvaluator):
-    """Backward-compatible alias: the round-model delta evaluator."""
-
-    def __init__(self, device: DeviceModel):
-        super().__init__(device, model="round")
 
 
 def _moves(n: int, neighborhood: str) -> list[tuple[int, str, int, int]]:
@@ -647,8 +626,6 @@ def refine_order(
     budget: int = 2000,
     model: str = "event",
     neighborhood: str = "full",
-    batch_size: int | None = None,
-    table=None,
     metrics=None,
 ) -> tuple[list[KernelProfile], float, int]:
     """Hill-climb ``order`` under ``time_fn``.
@@ -664,14 +641,6 @@ def refine_order(
     (suffix re-simulation from cached admission checkpoints) under
     both built-in models — ``model="round"`` and ``model="event"``;
     any custom ``time_fn`` falls back to full evaluation per candidate.
-
-    ``batch_size`` routes to the batched evaluator
-    (:func:`repro.core.batched.refine_order_batched`): the move
-    neighborhood is scored in vectorized ``(B, n)`` passes and the
-    improving moves re-verified exactly, same budget accounting.
-    Requires the default ``time_fn``.  ``table`` threads an
-    already-built :class:`~repro.core.fastscore.ProfileTable` through
-    so a greedy + refine pipeline packs the kernel set exactly once.
 
     ``budget`` is charged in *full-simulation equivalents*: a delta
     evaluation that re-simulates only the last k of n positions costs
@@ -693,14 +662,6 @@ def refine_order(
     Returns ``(best_order, best_time, evaluations_used)``.
     """
     n = len(order)
-    if batch_size is not None and time_fn is None \
-            and model in ("round", "event"):
-        from repro.core.batched import refine_order_batched
-
-        return refine_order_batched(
-            order, device, model=model, budget=budget,
-            neighborhood=neighborhood, batch_size=batch_size,
-            table=table, metrics=metrics)
     t_wall = perf_counter()
     if neighborhood == "auto":
         # Full neighbourhood while it still dominates the reference
@@ -774,20 +735,10 @@ def refined_schedule(
     budget: int = 2000,
     model: str = "event",
     neighborhood: str = "full",
-    batch_size: int | None = None,
 ) -> tuple[list[KernelProfile], float]:
     """Algorithm 1 (incremental fast path — identical schedules to the
-    reference) followed by local search.  Returns (order, time).
-
-    The :class:`~repro.core.fastscore.ProfileTable` built for the
-    greedy is threaded into the refiner, so the pipeline packs the
-    kernel set exactly once (the batched path reuses its cached device
-    arrays too)."""
-    from .fastscore import ProfileTable
-
-    table = ProfileTable.build(kernels, device)
-    sched: Schedule = greedy_order_fast(kernels, device, table=table)
+    reference) followed by local search.  Returns (order, time)."""
+    sched: Schedule = greedy_order_fast(kernels, device)
     order, t, _ = refine_order(sched.order, device, budget=budget,
-                               model=model, neighborhood=neighborhood,
-                               batch_size=batch_size, table=table)
+                               model=model, neighborhood=neighborhood)
     return order, t

@@ -43,17 +43,6 @@ extension of the event model —
 at the queue head until its predecessors drain, shares this module's
 :class:`EventCheckpoint` format (the gate state is derived on resume)
 and is delta-evaluated by :class:`repro.graph.delta.GatedDeltaEvaluator`.
-
-Both models also have *batched* twins that evaluate whole ``(B, n)``
-candidate batches at once from checkpoint-stitched suffixes —
-:class:`repro.core.batched.BatchedRoundSim` (bit-exact against the
-round model) and :class:`repro.core.batched.BatchedEventSim` (within
-pure summation-order float noise of the event/gated models) — plus an
-f32 single-order scan kernel of the event dispatcher,
-:func:`repro.kernels.event_scan.event_scan_core`, dispatchable as
-``jit(vmap)`` or a Pallas grid.  This module stays the semantic
-definition: every batched/kernel path is property-tested against the
-simulators here (``tests/test_batched.py``).
 """
 
 from __future__ import annotations

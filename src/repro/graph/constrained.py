@@ -451,24 +451,9 @@ def refine_order_dag(
     budget: int = 2000,
     model: str = "event",
     neighborhood: str = "full",
-    batch_size: int | None = None,
-    table=None,
-    rescore: bool | None = None,
     metrics=None,
 ) -> tuple[list[KernelProfile], float, int]:
     """Precedence-respecting hill-climb of a topological launch order.
-
-    ``batch_size`` routes to the batched evaluator
-    (:func:`repro.core.batched.refine_order_batched`): illegal
-    candidates are filtered for free as in the sequential path, the
-    legal neighborhood is scored in vectorized ``(B, n)`` passes
-    (gated candidates on the lockstep gated engine) and improving
-    moves are re-verified exactly before acceptance.  ``table``
-    threads a pre-built :class:`~repro.core.fastscore.ProfileTable`
-    through so the pipeline packs once.  ``rescore`` picks the
-    batched quality contract (sequential-parity vs
-    max-throughput; see :func:`repro.core.batched.refine_order_batched`
-    — the default re-scores under ``model="gated"``).
 
     ``edges`` are index pairs into the *given* ``order``; callers that
     hold a :class:`~repro.graph.kernel_graph.KernelGraph` over a
@@ -492,8 +477,8 @@ def refine_order_dag(
 
     ``metrics`` (a :class:`repro.obs.MetricsRegistry`) records
     ``refine_evals`` / ``refine_cost`` / ``refine_score_s`` exactly as
-    :func:`repro.core.refine.refine_order` does (and forwards to the
-    batched route) — purely additive, the trajectory is unchanged.
+    :func:`repro.core.refine.refine_order` does — purely additive, the
+    trajectory is unchanged.
     """
     n = len(order)
     base = list(order)
@@ -504,17 +489,6 @@ def refine_order_dag(
     legal = _legal_mask(base, edge_ids)
     if not legal(base):
         raise ValueError("input order violates the precedence edges")
-    if batch_size is not None and time_fn is None \
-            and model in ("round", "event", "gated"):
-        from repro.core.batched import refine_order_batched
-
-        return refine_order_batched(
-            base, device, model=model, budget=budget,
-            neighborhood=neighborhood, batch_size=batch_size,
-            table=table, edge_ids=edge_ids,
-            delta=(GatedDeltaEvaluator(device, edge_ids)
-                   if model == "gated" else None),
-            legal=legal, rescore=rescore, metrics=metrics)
     t_wall = perf_counter()
     use_delta = time_fn is None and model in ("round", "event", "gated")
     if not use_delta:

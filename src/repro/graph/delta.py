@@ -36,12 +36,6 @@ suffix re-simulation equals full gated re-simulation float-for-float
 on randomized DAGs, slice/join graphs (zero-work join markers) and
 the 0-edge degeneration, where the gated pipeline reproduces the
 ungated ``EventSimulator`` identity.
-
-The batched evaluator (:mod:`repro.core.batched`, reached through
-``refine_order_dag(..., batch_size=...)``) scores legal gated
-candidates in vectorized lockstep from this module's checkpoints and
-re-verifies every acceptance through :class:`GatedDeltaEvaluator`, so
-the batched trajectory stays in this exact currency.
 """
 
 from __future__ import annotations

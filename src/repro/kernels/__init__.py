@@ -3,13 +3,8 @@
 Each kernel ships three pieces: the ``pl.pallas_call`` implementation
 with explicit BlockSpec VMEM tiling, a pure-jnp oracle in ``ref.py``,
 and a jit'd public wrapper in ``ops.py``.
-
-:mod:`repro.kernels.event_scan` is the odd one out: not a model
-kernel but the scheduler's own event-dispatcher admission/completion
-scan, dispatched per candidate order (grid over the move batch) and
-property-tested against ``repro.core.refine._FastEventSim``.
 """
 
-from . import event_scan, ops, ref
+from . import ops, ref
 
-__all__ = ["event_scan", "ops", "ref"]
+__all__ = ["ops", "ref"]

@@ -312,9 +312,6 @@ class Composer:
                     model=model,
                     budget=self.policy.refine_budget,
                     neighborhood=self.policy.neighborhood,
-                    batch_size=(self.policy.refine_batch
-                                if self.policy.refine_backend == "batched"
-                                else None),
                     metrics=self.cache.metrics)
             prof_rounds = fifo_rounds_dag(order, self.device, sl_eids,
                                           demands_of=dem)
@@ -598,9 +595,6 @@ class Composer:
                         model=self.policy.refine_model,
                         budget=self.policy.refine_budget,
                         neighborhood=self.policy.neighborhood,
-                        batch_size=(self.policy.refine_batch
-                                    if self.policy.refine_backend
-                                    == "batched" else None),
                         metrics=self.cache.metrics)
             else:
                 # local search over the flat order, re-rounded by

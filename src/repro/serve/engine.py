@@ -23,8 +23,7 @@ its composition pipeline lives in :mod:`repro.serve.composer`
 (:class:`~repro.serve.composer.Composer`), the cache in
 :mod:`repro.serve.cache`, and the cross-step incremental frontier in
 :mod:`repro.serve.live` (:class:`~repro.serve.live.LiveComposition`).
-The historical import surface — ``ScheduleCache``, ``Signature``, the
-``ServingEngine._compose*`` helpers — is preserved here.
+``ScheduleCache`` and ``Signature`` are re-exported here.
 """
 
 from __future__ import annotations
@@ -190,17 +189,6 @@ class SchedulerPolicy:
     #: base seed for the audit baselines (each audited step derives a
     #: distinct deterministic seed from it).
     audit_seed: int = 0
-    #: Move-evaluation backend for the refinement passes: "host" is
-    #: the sequential delta evaluator; "batched" scores the move
-    #: neighborhood in vectorized ``(B, n)`` passes
-    #: (:func:`repro.core.batched.refine_order_batched`) with exact
-    #: re-verification before any acceptance — same budget accounting,
-    #: same result currency, ~3x+ effective-move throughput at
-    #: serving-scale n (see ``BENCH_scheduler_scaling.json``).
-    refine_backend: str = "host"
-    #: Candidate batch per vectorized pass when
-    #: ``refine_backend="batched"``.
-    refine_batch: int = 128
     #: How the respect_deps path composes across steps (PR 7):
     #: "batch" recomposes every step from scratch (optionally through
     #: the ScheduleCache); "incremental" keeps the ready-set greedy's
@@ -367,22 +355,6 @@ class ServingEngine:
             kv_bytes_per_token=self._kv_bytes_per_token(),
             max_stages=self.policy.dag_max_stages)
 
-    # -- composition (delegated; historical private surface) -----------
-    def _compose(self, items) -> list[list]:
-        return self.composer.compose(items)
-
-    def _compose_dag(self, triples, traced) -> list[list]:
-        return self.composer.compose_dag(triples, traced)
-
-    def _dag_gated_time(self, rounds, traced) -> float:
-        return self.composer.dag_gated_time(rounds, traced)
-
-    def _dag_key_and_labels(self, triples, traced):
-        return self.composer.dag_key_and_labels(triples, traced)
-
-    def _dag_round_time(self, rd) -> float:
-        return self.composer.dag_round_time(rd)
-
     # -- execution -------------------------------------------------------
     def submit(self, reqs: list[Request]) -> None:
         self.queue.extend(reqs)
@@ -477,13 +449,13 @@ class ServingEngine:
                 if self.live is not None:
                     rounds = self.live.compose_dag(triples, traced)
                 else:
-                    rounds = self._compose_dag(triples, traced)
-                time_of = self._dag_round_time
+                    rounds = self.composer.compose_dag(triples, traced)
+                time_of = self.composer.dag_round_time
             else:
                 items = self._work_items()
                 if not items:
                     return 0
-                rounds = self._compose(items)
+                rounds = self.composer.compose(items)
                 time_of = lambda rd: round_time(  # noqa: E731
                     [t[0] for t in rd], self.device, self.weights_bytes)
         # Online quality audit (PR 9): read-only over the composed

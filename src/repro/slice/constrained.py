@@ -192,8 +192,6 @@ def refine_order_slices(
     budget: int = 2000,
     model: str = "event",
     neighborhood: str = "adjacent",
-    batch_size: int | None = None,
-    rescore: bool | None = None,
     metrics=None,
 ) -> tuple[list[KernelProfile], float, int]:
     """Precedence-respecting local search over a sliced schedule's
@@ -204,14 +202,11 @@ def refine_order_slices(
     gated DAG makespan directly (delta-evaluated suffix re-simulation,
     see :func:`repro.graph.constrained.refine_order_dag`); ``"round"``
     and ``"event"`` remain the cheap precedence-blind proxies.
-    ``batch_size`` selects the batched move evaluator
-    (:func:`repro.core.batched.refine_order_batched`) as in
-    :func:`~repro.graph.constrained.refine_order_dag`; ``metrics``
-    forwards there too (``refine_evals`` / ``refine_cost`` /
-    ``refine_score_s``)."""
+    ``metrics`` forwards to
+    :func:`~repro.graph.constrained.refine_order_dag`
+    (``refine_evals`` / ``refine_cost`` / ``refine_score_s``)."""
     return refine_order_dag(result.order, device,
                             edge_ids=result.edges_by_id(),
                             budget=budget, model=model,
                             neighborhood=neighborhood,
-                            batch_size=batch_size, rescore=rescore,
                             metrics=metrics)

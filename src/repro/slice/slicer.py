@@ -28,7 +28,7 @@ peer:
   grid), while ``weight_bytes`` is *copied* to every slice — the
   parameter stream is a property of the stage, shared by its slices,
   and the serving round accounting
-  (:meth:`repro.serve.engine.ServingEngine._dag_round_time`) charges
+  (:meth:`repro.serve.composer.Composer.dag_round_time`) charges
   it once per distinct parent stage per round, never per slice.
 * :func:`join_profile` / :func:`join_item` — the synthetic
   zero-work join node the graph expansion hangs the parent's

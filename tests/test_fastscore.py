@@ -16,7 +16,7 @@ import pytest
 from repro.core import (GTX580, DeviceModel, KernelProfile, RoundSimulator,
                         greedy_order, greedy_order_fast, percentile_rank,
                         score_matrix, score_matrix_fast, simulate)
-from repro.core.refine import DeltaRoundEvaluator, refine_order
+from repro.core.refine import DeltaEvaluator, refine_order
 from repro.core.resources import bs_kernel, ep_kernel, es_kernel, sw_kernel
 from repro.core.scorer import combined_ratio, pair_score
 from repro.core.tpu import (decode_profile, make_serving_device,
@@ -133,7 +133,7 @@ def test_delta_eval_equals_full_resimulation(device, maker):
     for _ in range(20):
         ks = maker(rng, rng.randint(2, 20))
         n = len(ks)
-        ev = DeltaRoundEvaluator(device)
+        ev = DeltaEvaluator(device, model="round")
         ev.rebase(ks)
         for _ in range(25):
             i, j = rng.randrange(n), rng.randrange(n)
@@ -222,7 +222,7 @@ def test_sat_dim_configs_match_reference():
               for i in range(rng.randint(2, 12))]
         for dev in devs:
             ref = RoundSimulator(dev).simulate(ks)
-            ev = DeltaRoundEvaluator(dev)
+            ev = DeltaEvaluator(dev, model="round")
             assert ev.rebase(ks) == ref, (trial, dev.name)
             cand = list(ks)
             cand[0], cand[-1] = cand[-1], cand[0]
@@ -284,3 +284,24 @@ def test_fast_path_end_to_end_quality_not_worse():
         t_ref = simulate(o_ref, GTX580)
         t_fast = simulate(o_fast, GTX580)
         assert t_fast <= t_ref + 1e-12
+
+
+def test_refined_schedule_packs_profile_table_once(monkeypatch):
+    """Greedy + refine packs the kernel set into a ProfileTable once:
+    the delta-evaluated refiner resolves its own per-kernel tuples."""
+    from repro.core import refined_schedule
+    from repro.core.fastscore import ProfileTable
+
+    rng = random.Random(24)
+    ks = _gpu_kernels(rng, 24)
+    builds = []
+    real_build = ProfileTable.build.__func__
+
+    def counting_build(cls, kernels, device):
+        builds.append(len(kernels))
+        return real_build(cls, kernels, device)
+
+    monkeypatch.setattr(ProfileTable, "build",
+                        classmethod(counting_build))
+    refined_schedule(ks, GTX580, budget=20, neighborhood="adjacent")
+    assert builds == [len(ks)]

@@ -432,7 +432,7 @@ def test_metric_classes_standalone():
 
 
 # --------------------------------------------------------------------------
-# refinement metrics through the batched backend (the composer path)
+# refinement metrics on the delta-evaluated refiners (the composer path)
 # --------------------------------------------------------------------------
 
 @pytest.mark.parametrize("model", ["round", "event"])
@@ -441,7 +441,7 @@ def test_refine_batched_populates_metrics(model):
     ks = _gpu_kernels(rng, 12)
     m = MetricsRegistry()
     best, best_t, evals = refine_order(
-        ks, GTX580, model=model, budget=40, batch_size=4, metrics=m)
+        ks, GTX580, model=model, budget=40, metrics=m)
     snap = m.snapshot()
     assert snap["refine_evals"] == evals >= 1
     assert snap["refine_cost"] >= 1.0
@@ -449,7 +449,7 @@ def test_refine_batched_populates_metrics(model):
     assert snap["refine_score_s.total_s"] >= 0.0
     # metrics are purely additive: same trajectory with them off
     best2, best_t2, evals2 = refine_order(
-        ks, GTX580, model=model, budget=40, batch_size=4)
+        ks, GTX580, model=model, budget=40)
     assert best_t2 == best_t and evals2 == evals
     assert [k.name for k in best2] == [k.name for k in best]
 
@@ -465,13 +465,12 @@ def test_refine_dag_batched_gated_populates_metrics():
     m = MetricsRegistry()
     _, t, evals = refine_order_dag(
         ks, GTX580, edge_ids=edge_ids, model="gated", budget=20,
-        batch_size=8, metrics=m)
+        metrics=m)
     snap = m.snapshot()
     assert snap["refine_evals"] == evals >= 1
     assert snap["refine_score_s.count"] == 1
     _, t2, evals2 = refine_order_dag(
-        ks, GTX580, edge_ids=edge_ids, model="gated", budget=20,
-        batch_size=8)
+        ks, GTX580, edge_ids=edge_ids, model="gated", budget=20)
     assert (t2, evals2) == (t, evals)
 
 
@@ -563,30 +562,51 @@ def test_engine_instrumentation_bit_identical_and_phased():
                                         rel=1e-9)
 
 
-def test_engine_batched_refine_backend_records_metrics():
-    """The composer hands ``cache.metrics`` to every refinement call,
-    so the batched backend must accept a live registry end-to-end
-    (regression: refine_order_batched once referenced an undefined
-    ``t_wall`` whenever metrics were on)."""
+#: every refine route the composer keeps: (respect_deps, refine_model,
+#: extra policy fields)
+_REFINE_ROUTES = {
+    "flat-rounds": (False, "rounds", {}),
+    "flat-round": (False, "round", {}),
+    "flat-event": (False, "event", {}),
+    "dag-round": (True, "round", {}),
+    "dag-event": (True, "event", {}),
+    "dag-gated": (True, "gated", {}),
+    "dag-gated-sliced": (True, "gated", {"slice_policy": SlicePolicy(),
+                                         "dag_guard": "gated"}),
+}
+
+
+def _run_refine_engine(policy):
     jax = pytest.importorskip("jax")
     import numpy as np
 
     from repro.configs import get_config
     from repro.models import transformer as T
-    from repro.serve import Request, SchedulerPolicy, ServingEngine
+    from repro.serve import Request, ServingEngine
 
     cfg = get_config("qwen1.5-0.5b", "smoke")
     params = T.init(jax.random.PRNGKey(0), cfg)
-    eng = ServingEngine(cfg, params, max_len=32,
-                        policy=SchedulerPolicy(
-                            kind="refined", refine_model="event",
-                            refine_backend="batched", refine_batch=8,
-                            refine_budget=20))
+    eng = ServingEngine(cfg, params, max_len=32, policy=policy)
     rng = np.random.default_rng(0)
     eng.submit([Request(i, rng.integers(0, 128, size=4),
                         max_new_tokens=3) for i in range(2)])
-    stats = eng.run()
+    return eng.run()
+
+
+@pytest.mark.parametrize("route", list(_REFINE_ROUTES))
+def test_engine_batched_refine_backend_records_metrics(route):
+    """The composer hands ``cache.metrics`` to every refinement call:
+    each refine route records its evaluations end to end, and the
+    tokens match arrival-order (fifo) serving."""
+    from repro.serve import SchedulerPolicy
+
+    deps, model, extra = _REFINE_ROUTES[route]
+    stats = _run_refine_engine(SchedulerPolicy(
+        kind="refined", refine_model=model, refine_budget=20,
+        respect_deps=deps, **extra))
+    fifo = _run_refine_engine(SchedulerPolicy(kind="fifo"))
     assert stats["total_new_tokens"] > 0
+    assert stats["outputs"] == fifo["outputs"]
     snap = stats["metrics"]
     assert snap["refine_evals"] >= 1
     assert snap["refine_score_s.count"] >= 1

@@ -2,6 +2,8 @@
 work): signatures, key multisets, namespaced keys, pattern replay,
 LRU bound and refresh accounting, near-miss warm starts."""
 
+import pytest
+
 from repro.serve import ScheduleCache
 
 
@@ -201,3 +203,84 @@ def test_incremental_counters_surface_in_stats():
     assert s["incremental_leaves"] == 2
     assert s["frontier_rebuilds"] == 1
     assert abs(s["gated_sims_saved"] - 0.75) < 1e-12
+
+
+# --------------------------------------------------------------------------
+# the default flat composer: every outcome compose() can serve
+# --------------------------------------------------------------------------
+
+_N_PARAMS = 4.6e8            # qwen1.5-0.5b-sized weight stream
+_KV_BYTES = 98304.0          # its bf16 KV bytes per token
+
+
+def _flat_items(spec):
+    """Work-item triples for ``spec``: (rid, "p"|"d", prompt or kv len)."""
+    import numpy as np
+
+    from repro.core.tpu import decode_profile, prefill_profile
+    from repro.serve import Request
+
+    items = []
+    for rid, kind, n in spec:
+        r = Request(rid, np.zeros(n, np.int32))
+        if kind == "p":
+            it = prefill_profile(f"prefill:{rid}", n_params=_N_PARAMS,
+                                 seq_len=n, kv_bytes_per_token=_KV_BYTES)
+            items.append((it, r, "prefill"))
+        else:
+            r.cache, r.pos = object(), n
+            it = decode_profile(f"decode:{rid}", n_params=_N_PARAMS,
+                                kv_len=n, kv_bytes_per_token=_KV_BYTES)
+            items.append((it, r, "decode"))
+    return items
+
+
+_MIX = [(0, "p", 128), (1, "d", 1), (2, "d", 64), (3, "d", 1024),
+        (4, "d", 2048), (5, "p", 4)]
+
+#: outcome -> (steps composed in order, expected ``served`` of the last)
+_FLAT_OUTCOMES = {
+    "cold": ([_MIX], "cold"),
+    "fifo_guard": ([[(0, "p", 128), (1, "p", 4000), (2, "d", 4000)]],
+                   "fifo"),
+    "replay": ([_MIX, _MIX], "replay"),
+    "warm_join": ([_MIX, _MIX + [(6, "d", 300)]], "warm"),
+    "warm_leave": ([_MIX, _MIX[:-1]], "warm"),
+    # same signatures (kv lens stay in one bucket), but the decodes'
+    # KV reads grew past the drift tolerance: the replay is rejected
+    "stale_replay": ([[(i, "d", 1) for i in range(8)],
+                      [(i, "d", 4000) for i in range(8)]], "cold"),
+}
+
+
+@pytest.mark.parametrize("outcome", sorted(_FLAT_OUTCOMES))
+def test_default_flat_composer_outcomes(outcome):
+    from repro.core.tpu import fifo_rounds, make_serving_device, round_time
+    from repro.obs import FlightRecorder
+    from repro.serve import SchedulerPolicy
+    from repro.serve.composer import Composer
+
+    steps, served = _FLAT_OUTCOMES[outcome]
+    dev, wb = make_serving_device(), 2.0 * _N_PARAMS
+    rec = FlightRecorder()
+    # one decode signature up to 4K of KV, so the stale case's
+    # growing KV reads still hit the cached pattern
+    comp = Composer(SchedulerPolicy(), dev, wb,
+                    ScheduleCache(kv_bucket=4096), recorder=rec)
+    for spec in steps:
+        items = _flat_items(spec)
+        rounds = comp.compose(items)
+    notes = [e for e in rec.events if e["kind"] == "schedule"]
+    assert len(notes) == len(steps)
+    assert notes[-1]["served"] == served
+    if outcome == "stale_replay":
+        assert [e["outcome"] for e in rec.events
+                if e["kind"] == "cache"] == ["revalidated"]
+    # every live item exactly once
+    served_ids = sorted(id(t) for rd in rounds for t in rd)
+    assert served_ids == sorted(id(t) for t in items)
+    # never modelled-worse than arrival-order packing
+    t = sum(round_time([x[0] for x in rd], dev, wb) for rd in rounds)
+    t_fifo = sum(round_time(rd, dev, wb)
+                 for rd in fifo_rounds([x[0] for x in items], dev))
+    assert t <= t_fifo * (1 + 1e-12)

@@ -480,7 +480,7 @@ def test_serving_gated_guard_token_identity():
 
 
 def test_serving_gated_guard_scores_sliced_composition():
-    """``_dag_gated_time`` rebuilds the expanded slice/join dependency
+    """``Composer.dag_gated_time`` rebuilds the expanded slice/join dependency
     structure from item names: finite on a composition whose first
     stage was cut into slices + join, and ``inf`` (guard rejection)
     when the flat order breaks the slice diamond (join launched before
@@ -495,7 +495,7 @@ def test_serving_gated_guard_scores_sliced_composition():
     eng.submit(_smoke_requests())
     triples, traced = eng._work_items_dag()
     # hand-cut the first request's head stage into a slice diamond,
-    # exactly as _compose_dag's make_slices closure would
+    # exactly as compose_dag's make_slices closure would
     it0, r0, kind0 = triples[0]
     parts = KernelSlicer(SlicePolicy(mode="fixed", trigger_frac=0.0,
                                      fixed_k=2), dev).slice_item(it0, 2)
@@ -504,13 +504,13 @@ def test_serving_gated_guard_scores_sliced_composition():
     rounds = ([[(parts[0], r0, "frag"), (parts[1], r0, "frag")],
                [(ji, r0, kind0)]] +
               [[trip] for trip in triples[1:]])
-    t = eng._dag_gated_time(rounds, traced)
+    t = eng.composer.dag_gated_time(rounds, traced)
     assert 0.0 < t < float("inf")
     # join before its slices: non-topological flat order scores inf
     bad = ([[(ji, r0, kind0)],
             [(parts[0], r0, "frag"), (parts[1], r0, "frag")]] +
            [[trip] for trip in triples[1:]])
-    assert eng._dag_gated_time(bad, traced) == float("inf")
+    assert eng.composer.dag_gated_time(bad, traced) == float("inf")
 
 
 def test_gated_guard_unlocks_slicing_win_round_guard_hides():
@@ -539,10 +539,10 @@ def test_gated_guard_unlocks_slicing_win_round_guard_hides():
                             cache=False), dev)
         submit(eng)
         triples, traced = eng._work_items_dag()
-        rounds = eng._compose_dag(triples, traced)
+        rounds = eng.composer.compose_dag(triples, traced)
         names = [t[0].name for rd in rounds for t in rd]
         results[guard] = (sum(1 for nm in names if "#s" in nm),
-                          eng._dag_gated_time(rounds, traced))
+                          eng.composer.dag_gated_time(rounds, traced))
     assert results["rounds"][0] == 0, "round guard serves unsliced fifo"
     assert results["gated"][0] > 0, "gated guard accepts the slices"
     assert results["gated"][1] < results["rounds"][1]
@@ -550,7 +550,7 @@ def test_gated_guard_unlocks_slicing_win_round_guard_hides():
 
 def test_serving_refine_model_gated_runs():
     """kind="refined" with refine_model="gated" threads the gated
-    delta evaluator through _compose_dag; tokens match the symbiotic
+    delta evaluator through compose_dag; tokens match the symbiotic
     engine (refinement only reorders modelled rounds)."""
     from repro.serve import SchedulerPolicy
     dev = make_serving_device()
@@ -589,8 +589,8 @@ def test_dag_replay_reproduces_cold_composition():
                                         respect_deps=True),
                         make_serving_device())
     eng.submit(_smoke_requests())
-    cold = eng._compose_dag(*(eng._work_items_dag()[:2]))
-    warm = eng._compose_dag(*(eng._work_items_dag()[:2]))
+    cold = eng.composer.compose_dag(*(eng._work_items_dag()[:2]))
+    warm = eng.composer.compose_dag(*(eng._work_items_dag()[:2]))
     assert eng.schedule_cache.dag_hits == 1
     assert [[t[0].name for t in rd] for rd in warm] == \
         [[t[0].name for t in rd] for rd in cold]
@@ -608,20 +608,20 @@ def test_replay_drift_triggers_revalidation():
                         make_serving_device())
     eng.submit(_smoke_requests())
     triples, traced = eng._work_items_dag()
-    eng._compose_dag(triples, traced)          # cold store
-    key, _ = eng._dag_key_and_labels(triples, traced)
+    eng.composer.compose_dag(triples, traced)          # cold store
+    key, _ = eng.composer.dag_key_and_labels(triples, traced)
     t0 = eng.schedule_cache.time_of(key)
     assert t0 is not None and t0 > 0
     # poison the stored time: the honest replay now "drifts" >5%
     eng.schedule_cache._times[key] = t0 * 2.0
-    eng._compose_dag(*(eng._work_items_dag()[:2]))
+    eng.composer.compose_dag(*(eng._work_items_dag()[:2]))
     assert eng.schedule_cache.replay_revalidations == 1
     # the cold recompose re-stored the honest time
     assert eng.schedule_cache.time_of(key) == pytest.approx(t0)
     # tol <= 0 replays the same poisoned entry optimistically
     eng.schedule_cache._times[key] = t0 * 2.0
     eng.policy.replay_drift_tol = 0.0
-    eng._compose_dag(*(eng._work_items_dag()[:2]))
+    eng.composer.compose_dag(*(eng._work_items_dag()[:2]))
     assert eng.schedule_cache.replay_revalidations == 1
 
 

@@ -111,6 +111,46 @@ def test_serving_engine_generates_and_orders():
     assert stats["modelled_time_s"] > 0
 
 
+@pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "mixtral-8x7b",
+                                  "deepseek-v2-236b"])
+def test_default_policy_serves_benchmark_families(arch):
+    """The policy the benchmark serves (``SchedulerPolicy()``) on each
+    benchmarked family's smoke config: tokens equal arrival-order
+    (fifo) serving, and every step makes exactly one decode call per
+    live decoding request."""
+    from repro.configs import get_config
+    from repro.models import transformer as T
+    from repro.serve import Request, SchedulerPolicy, ServingEngine
+    cfg = get_config(arch, "smoke")
+    params = T.init(jax.random.PRNGKey(0), cfg)
+
+    def requests():
+        rng = np.random.default_rng(1)
+        return [Request(i, rng.integers(0, cfg.vocab, size=n),
+                        max_new_tokens=4) for i, n in enumerate((5, 3, 6))]
+
+    fifo = ServingEngine(cfg, params, max_len=32,
+                         policy=SchedulerPolicy(kind="fifo"))
+    fifo.submit(requests())
+    want = fifo.run()["outputs"]
+    eng = ServingEngine(cfg, params, max_len=32, policy=SchedulerPolicy())
+    first, late = requests()[:2], requests()[2:]
+    eng.submit(first)
+    decode = eng.metrics.counter("decode_calls", kind="decode")
+    steps = 0
+    while True:
+        if steps == 1:
+            eng.submit(late)           # joins a step with live decodes
+        live = sum(1 for r in eng.queue
+                   if not r.done and r.cache is not None)
+        before = decode.value
+        if not eng.step():
+            break
+        assert decode.value - before == live
+        steps += 1
+    assert {r.rid: list(r.generated) for r in eng.queue} == want
+
+
 def test_serving_warm_start_on_arrival():
     """A request joining a steady mix is a cache near-miss: the engine
     must adapt the cached composition (warm start) instead of
